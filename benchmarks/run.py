@@ -14,6 +14,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
 
+    from benchmarks.compile_cache import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}")
+
     from benchmarks import (bench_chunk_step, bench_engine,
                             bench_latency_fidelity, bench_policies,
                             bench_request_volume, bench_serve, bench_speedup,

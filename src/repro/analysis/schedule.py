@@ -35,10 +35,7 @@ import pathlib
 
 from .common import Finding, eqn_loc, rel, scan_body_info, trace_step_ref
 
-try:  # jax >= 0.4.33 moved the public jaxpr types
-    from jax.extend.core import Literal, Var
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Literal, Var  # type: ignore
+from jax.extend.core import Literal, Var
 
 PASS = "schedule"
 

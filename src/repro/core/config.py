@@ -88,10 +88,12 @@ class EmulatorConfig:
     #   "on"   — run the whole per-chunk step (gather, redirect, bank
     #            resolve, in-order return, commit, policy proposal) as ONE
     #            pallas_call with the packed table staged through VMEM
-    #            (interpret mode off-TPU, so tests can force it anywhere)
+    #            (interpret mode off-TPU, so tests can force it anywhere;
+    #            the TPU compiler refuses it, so "on" raises on a TPU)
     #   "off"  — the composable jnp scan path (bitwise identical)
-    #   "auto" — kernel when the Pallas dispatch says so (TPU, or
-    #            REPRO_FORCE_PALLAS=1) and the table fits the VMEM budget
+    #   "auto" — the scan path on a TPU (the kernel is in
+    #            kernels.ops.REFUSED_ON_TPU); the kernel only where
+    #            REPRO_FORCE_PALLAS=1 and the table fits the VMEM budget
     #   Resolution in kernels.chunk_step.use_chunk_step_kernel.
 
     # --- policy -------------------------------------------------------------------

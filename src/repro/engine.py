@@ -43,7 +43,8 @@ from typing import Iterable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core import counters as counters_lib
 from repro.core.config import (EmulatorConfig, RuntimeParams,
@@ -78,10 +79,9 @@ def stack_params(points: list[DesignPoint]) -> RuntimeParams:
 
 
 def sweep_mesh():
-    """A 1-D device mesh over every local device, for sharded sweeps."""
-    from repro.launch.mesh import make_dev_mesh
-
-    return make_dev_mesh(model=1)
+    """A 1-D device mesh over every local device, for sharded sweeps
+    (auto axis types: the point axis is placed by ``NamedSharding``)."""
+    return Mesh(np.asarray(jax.devices()), ("data",))
 
 
 def _prefetched(segments, depth: int):

@@ -51,12 +51,14 @@ Nothing mid-pipeline reads a mid-chunk write; FLAGS is only written at
 boundaries (the swap commit's poison travel and the retirement stamp),
 never on the hot path.
 
-TPU note: the body gathers/scatters table rows by value index, which
-interpret mode (and the bit-identity suite) exercises everywhere; on a
-real TPU the gather lowers via the same dynamic-slice machinery as the
-lookup kernel, and the VMEM budget check in
-:func:`use_chunk_step_kernel` keeps the resident table within a core's
-VMEM (paper geometry: 294912 rows x 8 lanes x 4 B ~ 9.4 MB of ~16 MB).
+TPU note: the TPU compiler refuses the kernel ("Can only load scalars
+from SMEM": the body reads a vector from the scalar-prefetch operand).
+Behind that, the whole table is declared as both an input and an output
+VMEM block (2 x 9.4 MB at paper geometry, with no
+``input_output_aliases`` and no ``vmem_limit_bytes``), and the body
+gathers, scatters and sorts by value index. So "auto" never selects it
+on a TPU (``kernels.ops.REFUSED_ON_TPU``); it runs in interpret mode
+off-TPU, where the bit-identity suite exercises it.
 """
 from __future__ import annotations
 
@@ -832,10 +834,12 @@ def _pallas_step_fn(cfg: EmulatorConfig, registry: PolicyRegistry,
 
 def use_chunk_step_kernel(cfg: EmulatorConfig) -> bool:
     """Resolve the ``chunk_step_kernel`` knob (static, host-side): "on"
-    forces the kernel (interpret mode off-TPU — how CPU tests run it),
-    "off" forces the scan path, "auto" follows the same dispatch as
-    ``hmmu_lookup`` (:func:`kernels.ops.use_pallas`) with a VMEM budget
-    check on the resident table."""
+    forces the kernel (interpret mode off-TPU — how CPU tests run it; on
+    a TPU the compiler refuses it and the call raises), "off" forces the
+    scan path, "auto" takes the kernel only where
+    :func:`kernels.ops.use_pallas` selects ``"chunk_step"`` (never by
+    default on a TPU, which refuses it; REPRO_FORCE_PALLAS=1 anywhere)
+    and the table fits the VMEM budget."""
     knob = cfg.chunk_step_kernel
     if knob == "off":
         return False
@@ -844,7 +848,7 @@ def use_chunk_step_kernel(cfg: EmulatorConfig) -> bool:
     if knob != "auto":
         raise ValueError(f"unknown chunk_step_kernel {knob!r}; expected "
                          "'auto', 'on' or 'off'")
-    return (kernel_ops.use_pallas() and
+    return (kernel_ops.use_pallas("chunk_step") and
             cfg.n_pages * table_lib.ROW_W * 4 <= VMEM_TABLE_BUDGET)
 
 

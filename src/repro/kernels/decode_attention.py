@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -117,7 +113,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qr, kr, vr)

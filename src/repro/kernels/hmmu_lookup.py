@@ -17,9 +17,14 @@ kernel launch. Page indices are clamped to the table extent before the
 gather — an out-of-range page can never make the index_map fetch an
 arbitrary row.
 
-W=8 keeps rows compact; on a real TPU the row tile pads to the (8, 128)
-int32 native tile, which the dry-run roofline accounts as the gather's
-bandwidth cost.
+W=8 keeps rows compact, and that is why the TPU compiler refuses this
+kernel: a (1, 1, 8) block is below the (8, 128) int32 tile, and the
+chip tiles the table's 8-lane rows to 128 lanes in HBM, so a row DMA
+out of an HBM-resident table is refused too. On a TPU the default path
+therefore uses XLA's native gather (``kernels.ops.REFUSED_ON_TPU``);
+this kernel runs in interpret mode off-TPU. A TPU form would view the
+table as ``int32[n_pages * 8 / 128, 128]`` and select the row from the
+fetched tile.
 """
 from __future__ import annotations
 

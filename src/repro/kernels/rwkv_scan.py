@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *,
             chunk: int):
@@ -94,7 +90,7 @@ def rwkv_chunk_scan(r, k, v, logw, u, chunk: int = 128,
         out_specs=pl.BlockSpec((1, c, dv), lambda i, n: (i, n, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, dv), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(flat(r), flat(k), flat(v), flat(logw), u_flat)

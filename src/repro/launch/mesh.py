@@ -6,13 +6,20 @@ jax device state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto (not Explicit) axes: the emulator's sharded sweep places its
+    # inputs with NamedSharding and lets XLA propagate the rest.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds the 2-pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_dev_mesh(model: int = 2, data: int | None = None):
@@ -21,4 +28,4 @@ def make_dev_mesh(model: int = 2, data: int | None = None):
     n = len(jax.devices())
     model = min(model, n)
     data = data or n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -210,7 +210,10 @@ class PagedKVMap:
         if k == 0:
             return np.empty(0, np.int32)
         age = np.where(cand, self.last_access, _NEVER)
-        victims = np.argpartition(age, k - 1)[:k].astype(np.int32)
+        # Oldest first, ties by page number. A stable sort, because the
+        # tie order of np.argpartition follows the host CPU's SIMD
+        # dispatch, and the victims' order is the free-stack order.
+        victims = np.argsort(age, kind="stable")[:k].astype(np.int32)
         self.page_of[self.owner[victims], self.owner_idx[victims]] = -1
         self._free(victims)
         self.evictions += k

@@ -234,6 +234,23 @@ def test_kv_map_eviction_is_lru_and_skips_pinned():
     assert kv.page_of[1, 1] == -1         # mapping cleared for the victim
 
 
+def test_kv_map_eviction_breaks_ties_by_page_number():
+    """Equally old candidates go lowest page number first, in that order
+    (the free-stack order): the victims, and so every emulated serving
+    statistic, do not depend on the host CPU's SIMD dispatch."""
+    cfg = _platform(n_fast_pages=1024, n_slow_pages=3072)
+    kv = PagedKVMap(cfg, max_live_seqs=1024, max_pages_per_seq=4,
+                    pin_pages_per_seq=0, free_low_frac=0.5,
+                    free_high_frac=0.6)
+    pages = kv.alloc(3000)
+    kv.assign(np.arange(3000) // 4, np.arange(3000) % 4, pages, step=1)
+    kv.touch(pages[::7], 2)               # a younger minority
+    victims = kv.maybe_evict(step=3)
+    old = np.sort(np.setdiff1d(pages, pages[::7]))
+    np.testing.assert_array_equal(victims, old[:len(victims)])
+    assert 0 < len(victims) < len(old)
+
+
 def test_kv_map_release_returns_contracted_pages():
     cfg = _platform(n_fast_pages=8, n_slow_pages=8)
     kv = PagedKVMap(cfg, max_live_seqs=2, max_pages_per_seq=4,
