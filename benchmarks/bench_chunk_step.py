@@ -17,10 +17,10 @@ tunes on it, on the default paper geometry (n_banks=16, chunk=512):
   off-TPU, so its absolute number is only meaningful on real hardware —
   benched at a reduced request count).
 
-It also reports a per-stage breakdown of the chunk step itself (RX link /
-gather / bank resolve / in-order return / boundary commit / policy),
-measured by timing stage-truncated scans (``kernels.chunk_step.step_until``)
-and differencing successive stages.
+Device time per phase and stage of the chunk step comes from the named
+scopes in the program, read from a traced run of a benchmark cell on the
+chip (``python3 hbench/trace_scopes.py``, reduced by
+``hbench/scopes.py``).
 
 Runnable standalone::
 
@@ -43,75 +43,12 @@ import jax
 from benchmarks.bench_throughput import _bench  # shared warm-then-average
 from benchmarks.schema import (add_check_args, bench_payload, run_check,
                                write_bench_json)
-import jax.numpy as jnp
-
 from repro import Engine
-from repro.core import init_state, pad_trace, paper_platform
-from repro.kernels import chunk_step as chunk_step_lib
+from repro.core import paper_platform
 from repro.trace import TraceSpec, generate
 
 # The default hot path: what plain paper_platform() users get.
 _DEFAULT_CASE = "resolver=auto/gather=fused"
-
-# step_until stages in pipeline order; each breakdown entry is the delta
-# between a stage-truncated scan and its predecessor.
-_STAGE_ORDER = ("rx", "gather", "resolve", "return", "commit", "full")
-_STAGE_LABEL = {"rx": "rx_link", "gather": "gather", "resolve": "resolve",
-                "return": "inorder_return", "commit": "boundary_commit",
-                "full": "policy"}
-
-
-def _stage_breakdown(base, trace, reps, n, verbose):
-    """us/req per chunk-step stage: time a scan of ``step_until`` at each
-    truncation point and difference successive stages. The truncated
-    steps keep the full carry structure, so each timing is a real
-    end-to-end scan, not an isolated microkernel."""
-    engine = Engine(base)
-    params, registry = engine.params, engine.registry
-    padded, valid = pad_trace(base, trace)
-    n_chunks = padded.page.shape[0] // base.chunk
-    chunks = jax.tree.map(lambda x: x.reshape(n_chunks, base.chunk),
-                          padded)
-    vchunks = valid.reshape(n_chunks, base.chunk)
-    state0 = init_state(base, params)
-    sc0 = chunk_step_lib.StepScalars(
-        clock=state0.clock, clock_ptr=state0.clock_ptr,
-        chunk_idx=state0.chunk_idx, dma=state0.dma,
-        link_free_rx=state0.link_free_rx, link_free_tx=state0.link_free_tx,
-        last_return=state0.last_return)
-
-    times = {}
-    for stage in _STAGE_ORDER:
-        @jax.jit
-        def run(table, bank_free, _stage=stage):
-            def body(carry, xs):
-                table, sc, bank_free = carry
-                (page, offset, is_write, size), v = xs
-                table, sc, bank_free, outs = chunk_step_lib.step_until(
-                    base, registry, table, params, sc, bank_free,
-                    page, offset, is_write, size, v, upto=_stage)
-                # keep every stage's products live (returns/device plus
-                # the whole carry below), or XLA dead-code-eliminates the
-                # truncated stages and the deltas read as zero
-                return (table, sc, bank_free), (outs["returns"],
-                                                outs["device"],
-                                                outs["latency"])
-            carry, ys = jax.lax.scan(
-                body, (table, sc0, bank_free), (chunks, vchunks))
-            return carry, ys
-        fn = lambda: jax.block_until_ready(  # noqa: E731
-            run(state0.table, state0.bank_free))
-        times[stage] = _bench(fn, reps)
-
-    breakdown, prev = {}, 0.0
-    for stage in _STAGE_ORDER:
-        us = max(times[stage] - prev, 0.0) / n * 1e6
-        breakdown[f"us_per_req_stage_{_STAGE_LABEL[stage]}"] = us
-        prev = times[stage]
-        if verbose:
-            print(f"  stage {_STAGE_LABEL[stage]:16s} {us:8.3f} us/req "
-                  f"(cumulative {times[stage] / n * 1e6:8.3f})")
-    return breakdown
 
 
 def run(verbose=True, n=32_768, reps=5, out=None):
@@ -181,10 +118,6 @@ def run(verbose=True, n=32_768, reps=5, out=None):
               f"{sec_kernel * 1e3:9.1f} ms/call "
               f"{rows[-1]['us_per_req']:8.3f} us/req  (n={n_kernel})")
 
-    if verbose:
-        print("  per-stage breakdown (scan path, stage-truncated scans):")
-    breakdown = _stage_breakdown(base, trace, reps, n, verbose)
-
     metrics = {
         "n_requests": n,
         "us_per_req_default": sec_default / n * 1e6,
@@ -196,7 +129,6 @@ def run(verbose=True, n=32_768, reps=5, out=None):
         "speedup_fused_vs_unfused": sec_unfused / sec_default,
         "speedup_donate": sec_nodon / sec_don,
         "us_per_req_kernel_interpret": sec_kernel / n_kernel * 1e6,
-        **breakdown,
     }
     if verbose:
         print(f"  vs pre-PR path: {metrics['speedup_vs_pre_pr']:.2f}x, "

@@ -129,7 +129,8 @@ def _chunk_step(cfg: EmulatorConfig, params: RuntimeParams,
     policy reads the committed table). Here we only split state into the
     kernel's carry (scalars + table + bank_free), step it, and fold the
     chunk's results into the float counter accumulators — which stay
-    outside the kernel, int32-in float32-out.
+    outside the kernel, int32-in float32-out — under the named scope
+    ``hmmu.counters``.
     """
     trace, valid = chunk
     page, offset, is_write, size = trace
@@ -143,12 +144,13 @@ def _chunk_step(cfg: EmulatorConfig, params: RuntimeParams,
     table, sc, bank_free, outs = chunk_step_lib.chunk_step(
         cfg, registry, state.table, params, sc, state.bank_free,
         page, offset, is_write, size, valid, faults)
-    ctr = counters_lib.update(params, state.counters, device=outs["device"],
-                              is_write=is_write, size=size, valid=valid,
-                              latency=outs["latency"], held=outs["held"],
-                              poisoned=outs["poisoned"],
-                              retired=outs["retired"] >= 0,
-                              injected=outs["injected"])
+    with jax.named_scope("hmmu.counters"):
+        ctr = counters_lib.update(
+            params, state.counters, device=outs["device"],
+            is_write=is_write, size=size, valid=valid,
+            latency=outs["latency"], held=outs["held"],
+            poisoned=outs["poisoned"], retired=outs["retired"] >= 0,
+            injected=outs["injected"])
     new_state = EmulatorState(
         table=table, clock_ptr=sc.clock_ptr, chunk_idx=sc.chunk_idx,
         dma=sc.dma, clock=sc.clock, bank_free=bank_free,

@@ -188,37 +188,33 @@ def _seq_inorder(complete: jax.Array, last_return: jax.Array) -> jax.Array:
 def pipeline_phase(cfg: EmulatorConfig, params: RuntimeParams,
                    table: jax.Array, sc: StepScalars, bank_free: jax.Array,
                    page, offset, is_write, size, valid, *,
-                   seq: bool = False, upto: str = "full") -> PipelineOut:
+                   seq: bool = False) -> PipelineOut:
     """Stages 1-5 of the paper's Fig 2 workflow: RX link, table lookup +
     DMA-conflict redirect, bank queues + media access, tag-match in-order
     return, TX link. Touches the table READ-ONLY (schedule contract §1).
+    Each stage runs under its own named scope (``rx``, ``lookup``,
+    ``banks``, ``return``, ``tx``).
 
     ``seq=True`` selects the in-kernel sequential recurrences (the Pallas
-    body); default is the closed-form scan path. ``upto`` truncates after
-    a named stage ("rx" / "gather" / "resolve") for the per-stage bench —
-    missing fields come back zeroed.
+    body); default is the closed-form scan path.
     """
     n = page.shape[0]
-    size = jnp.where(valid, size, 0)
     mp = _seq_maxplus if seq else latency.maxplus_scan
-    zv = jnp.zeros(n, jnp.int32)
-    zs = jnp.zeros((), jnp.int32)
-    zrow = jnp.zeros(table.shape[-1], jnp.int32)
 
     # --- stage 1: RX link (host -> HMMU). Writes carry payload, reads a
     # header.
-    issue = sc.clock + params.issue_gap * (1 + jnp.arange(n, dtype=jnp.int32))
-    issue = jnp.where(valid, issue, _NEG)
-    rx_bytes = jnp.where(is_write, size, 16)
-    rx_srv = jnp.where(valid, latency.link_service_cycles(params, rx_bytes), 0)
-    rx_done = mp(
-        jnp.maximum(issue, jnp.where(valid, sc.link_free_rx, _NEG)),
-        rx_srv)
-    arrive = rx_done + jnp.where(valid, params.link_lat // 2, 0)
-    if upto == "rx":
-        return PipelineOut(zv, zv, zrow, zrow, zv, zv, zs,
-                           jnp.zeros(n, bool), bank_free, rx_done[-1], zs,
-                           zv)
+    with jax.named_scope("rx"):
+        size = jnp.where(valid, size, 0)
+        issue = sc.clock + params.issue_gap * (
+            1 + jnp.arange(n, dtype=jnp.int32))
+        issue = jnp.where(valid, issue, _NEG)
+        rx_bytes = jnp.where(is_write, size, 16)
+        rx_srv = jnp.where(valid,
+                           latency.link_service_cycles(params, rx_bytes), 0)
+        rx_done = mp(
+            jnp.maximum(issue, jnp.where(valid, sc.link_free_rx, _NEG)),
+            rx_srv)
+        arrive = rx_done + jnp.where(valid, params.link_lat // 2, 0)
 
     # --- stage 2: redirection-table lookup (+ DMA swap-progress redirect).
     # One packed-row fetch — the BRAM read per cycle of the paper's
@@ -227,60 +223,60 @@ def pipeline_phase(cfg: EmulatorConfig, params: RuntimeParams,
     # DMA swap pair, chunk + 2 rows in one launch). Inside the one-kernel
     # body the table is already VMEM-resident, so the gather is a direct
     # row index. All paths clamp indices identically.
-    a = jnp.maximum(sc.dma.page_a, 0)
-    b = jnp.maximum(sc.dma.page_b, 0)
-    if seq:
-        pg = jnp.clip(page, 0, table.shape[0] - 1)
-        rows = table[pg]
-        row_a, row_b = table[a], table[b]
-    elif cfg.fuse_swap_gather:
-        rows, swap_rows = kernel_ops.hmmu_lookup_fused(
-            table, page, jnp.stack([a, b]))
-        row_a, row_b = swap_rows[..., 0, :], swap_rows[..., 1, :]
-    else:
-        rows = kernel_ops.hmmu_lookup(table, page)
-        row_a, row_b = table[a], table[b]
-    dev = table_lib.device(rows)
-    frm = table_lib.frame(rows)
-    hot_pre = table_lib.hotness(rows)
-    dev, frm = dma_lib.redirect(
-        cfg, sc.dma, page, offset, arrive, dev, frm, row_a, row_b, params)
-    poisoned = valid & table_lib.is_poisoned(rows)
-    if upto == "gather":
-        return PipelineOut(dev, frm, row_a, row_b, zv, zv, zs, poisoned,
-                           bank_free, rx_done[-1], zs, hot_pre)
+    with jax.named_scope("lookup"):
+        a = jnp.maximum(sc.dma.page_a, 0)
+        b = jnp.maximum(sc.dma.page_b, 0)
+        if seq:
+            pg = jnp.clip(page, 0, table.shape[0] - 1)
+            rows = table[pg]
+            row_a, row_b = table[a], table[b]
+        elif cfg.fuse_swap_gather:
+            rows, swap_rows = kernel_ops.hmmu_lookup_fused(
+                table, page, jnp.stack([a, b]))
+            row_a, row_b = swap_rows[..., 0, :], swap_rows[..., 1, :]
+        else:
+            rows = kernel_ops.hmmu_lookup(table, page)
+            row_a, row_b = table[a], table[b]
+        dev = table_lib.device(rows)
+        frm = table_lib.frame(rows)
+        hot_pre = table_lib.hotness(rows)
+        dev, frm = dma_lib.redirect(
+            cfg, sc.dma, page, offset, arrive, dev, frm, row_a, row_b, params)
+        poisoned = valid & table_lib.is_poisoned(rows)
 
     # --- stage 3: per-device bank queues + media access.
-    bank = dev * cfg.n_banks + frm % cfg.n_banks
-    med_srv = jnp.where(
-        valid, latency.device_service_cycles(params, dev, is_write, size), 0)
-    if seq:
-        med_done, bank_free2 = _seq_bank_resolve(arrive, med_srv, bank,
-                                                 bank_free)
-    else:
-        resolve = (latency.resolve_bank_queues_segmented
-                   if latency.pick_bank_resolver(cfg) == "segmented"
-                   else latency.resolve_bank_queues)
-        med_done, bank_free2 = resolve(
-            arrive, med_srv, bank, 2 * cfg.n_banks, bank_free)
-    if upto == "resolve":
-        return PipelineOut(dev, frm, row_a, row_b, zv, zv, zs, poisoned,
-                           bank_free2, rx_done[-1], zs, hot_pre)
+    with jax.named_scope("banks"):
+        bank = dev * cfg.n_banks + frm % cfg.n_banks
+        med_srv = jnp.where(
+            valid, latency.device_service_cycles(params, dev, is_write, size),
+            0)
+        if seq:
+            med_done, bank_free2 = _seq_bank_resolve(arrive, med_srv, bank,
+                                                     bank_free)
+        else:
+            resolve = (latency.resolve_bank_queues_segmented
+                       if latency.pick_bank_resolver(cfg) == "segmented"
+                       else latency.resolve_bank_queues)
+            med_done, bank_free2 = resolve(
+                arrive, med_srv, bank, 2 * cfg.n_banks, bank_free)
 
     # --- stage 4: tag-match in-order return (paper §III-C) ...
-    inorder = _seq_inorder if seq else consistency.in_order_returns
-    ordered = inorder(jnp.where(valid, med_done, _NEG),
-                      sc.last_return)
-    held = jnp.sum((ordered > med_done) & valid).astype(jnp.int32)
+    with jax.named_scope("return"):
+        inorder = _seq_inorder if seq else consistency.in_order_returns
+        ordered = inorder(jnp.where(valid, med_done, _NEG),
+                          sc.last_return)
+        held = jnp.sum((ordered > med_done) & valid).astype(jnp.int32)
 
     # --- stage 5: ... then TX link serialization (responses leave in
     # order).
-    tx_bytes = jnp.where(is_write, 16, size)
-    tx_srv = jnp.where(valid, latency.link_service_cycles(params, tx_bytes), 0)
-    returns = mp(
-        jnp.maximum(ordered, jnp.where(valid, sc.link_free_tx, _NEG)),
-        tx_srv) + jnp.where(valid, params.link_lat // 2, 0)
-    lat = jnp.where(valid, returns - issue, 0)
+    with jax.named_scope("tx"):
+        tx_bytes = jnp.where(is_write, 16, size)
+        tx_srv = jnp.where(valid,
+                           latency.link_service_cycles(params, tx_bytes), 0)
+        returns = mp(
+            jnp.maximum(ordered, jnp.where(valid, sc.link_free_tx, _NEG)),
+            tx_srv) + jnp.where(valid, params.link_lat // 2, 0)
+        lat = jnp.where(valid, returns - issue, 0)
     return PipelineOut(dev, frm, row_a, row_b, returns, lat, held, poisoned,
                        bank_free2, rx_done[-1], returns[-1], hot_pre)
 
@@ -326,86 +322,92 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
     n = page.shape[0]
     w_lanes = table.shape[-1]
     n_pages = table.shape[0]
-    any_valid = jnp.any(valid)
-    last_ret = jnp.where(
-        any_valid, jnp.max(jnp.where(valid, pipe.returns, sc.last_return)),
-        sc.last_return)
-    now = jnp.maximum(sc.clock + params.issue_gap * n, last_ret)
+    with jax.named_scope("deltas"):
+        any_valid = jnp.any(valid)
+        last_ret = jnp.where(
+            any_valid, jnp.max(jnp.where(valid, pipe.returns, sc.last_return)),
+            sc.last_return)
+        now = jnp.maximum(sc.clock + params.issue_gap * n, last_ret)
 
-    # Hotness accumulation (decayed below, after the combined scatter —
-    # nothing else in the scatter touches the HOTNESS lane). Weights are
-    # clipped against the pre-chunk lane value so the counter saturates
-    # at HOTNESS_CAP instead of wrapping — exact under duplicate pages,
-    # identity below the cap.
-    hot_w = 1 + (jnp.asarray(eff_weight, jnp.int32) - 1) * \
-        is_write.astype(jnp.int32)
-    hot_w = jnp.where(valid, hot_w, 0)
-    hot_w = table_lib.saturating_weights(page, hot_w, pipe.hot_pre,
-                                         table_lib.HOTNESS_CAP)
-    # NVM endurance: demand writes per slow frame (the DMA migration's
-    # full-page write is charged by the swap commit's WEAR deltas).
-    slow_wr = is_write & valid & (pipe.dev == SLOW)
+        # Hotness accumulation (decayed below, after the combined scatter —
+        # nothing else in the scatter touches the HOTNESS lane). Weights are
+        # clipped against the pre-chunk lane value so the counter saturates
+        # at HOTNESS_CAP instead of wrapping — exact under duplicate pages,
+        # identity below the cap.
+        hot_w = 1 + (jnp.asarray(eff_weight, jnp.int32) - 1) * \
+            is_write.astype(jnp.int32)
+        hot_w = jnp.where(valid, hot_w, 0)
+        hot_w = table_lib.saturating_weights(page, hot_w, pipe.hot_pre,
+                                             table_lib.HOTNESS_CAP)
+        # NVM endurance: demand writes per slow frame (the DMA migration's
+        # full-page write is charged by the swap commit's WEAR deltas).
+        slow_wr = is_write & valid & (pipe.dev == SLOW)
 
-    # DMA swap commit, planned from the stage-2 prefetched rows.
-    swap_a = jnp.maximum(sc.dma.page_a, 0)  # pre-completion swap pair
-    plan = dma_lib.plan_commit(cfg, sc.dma, now, pipe.row_a, pipe.row_b,
-                               params, sc.rescue_page)
-    # OWNER inverse map (fast frame -> owning page, the CLOCK victim
-    # rotation): the promoted page (swap_a, now FAST) owns its new frame.
-    # No swap completed => route the write through an out-of-range
-    # sentinel dropped by the scatter, so row 0's OWNER lane can never be
-    # clobbered by the idle guard index.
-    db = table_lib.device(pipe.row_b)
-    fb = table_lib.frame(pipe.row_b)
-    promoted = plan.done & (db == FAST)
-    own_pre = table[fb, table_lib.OWNER]
-    own_idx = jnp.where(promoted, fb * w_lanes + table_lib.OWNER,
-                        n_pages * w_lanes)
-    own_delta = jnp.where(promoted, swap_a - own_pre, 0)
+        # DMA swap commit, planned from the stage-2 prefetched rows.
+        swap_a = jnp.maximum(sc.dma.page_a, 0)  # pre-completion swap pair
+        plan = dma_lib.plan_commit(cfg, sc.dma, now, pipe.row_a, pipe.row_b,
+                                   params, sc.rescue_page)
+        # OWNER inverse map (fast frame -> owning page, the CLOCK victim
+        # rotation): the promoted page (swap_a, now FAST) owns its new frame.
+        # No swap completed => route the write through an out-of-range
+        # sentinel dropped by the scatter, so row 0's OWNER lane can never be
+        # clobbered by the idle guard index.
+        db = table_lib.device(pipe.row_b)
+        fb = table_lib.frame(pipe.row_b)
+        promoted = plan.done & (db == FAST)
+        own_pre = table[fb, table_lib.OWNER]
+        own_idx = jnp.where(promoted, fb * w_lanes + table_lib.OWNER,
+                            n_pages * w_lanes)
+        own_delta = jnp.where(promoted, swap_a - own_pre, 0)
 
-    # WEAR saturation: demand charges and the swap commit's migration
-    # charges can land on the SAME slow frame in one boundary, so both
-    # sources join ONE fill-until-full pass against the pre-chunk WEAR
-    # (one extra pre-commit single-lane gather — a read, schedule §1).
-    # The plan keeps its non-WEAR deltas; its WEAR entries move into the
-    # joint fill (scatter-add totals are order-independent, so below the
-    # cap this is bitwise the historical commit).
-    wear_mask = plan.lanes == table_lib.WEAR
-    wear_rows = jnp.concatenate([
-        jnp.where(slow_wr, pipe.frm, 0),
-        jnp.where(wear_mask, plan.rows, 0)])
-    wear_w = jnp.concatenate([
-        slow_wr.astype(jnp.int32),
-        jnp.where(wear_mask, plan.delta, 0)])
-    wear_pre = table[wear_rows, table_lib.WEAR]
-    wear_w = table_lib.saturating_weights(wear_rows, wear_w, wear_pre,
-                                          table_lib.WEAR_CAP)
-    plan_delta = jnp.where(wear_mask, 0, plan.delta)
+        # WEAR saturation: demand charges and the swap commit's migration
+        # charges can land on the SAME slow frame in one boundary, so both
+        # sources join ONE fill-until-full pass against the pre-chunk WEAR
+        # (one extra pre-commit single-lane gather — a read, schedule §1).
+        # The plan keeps its non-WEAR deltas; its WEAR entries move into the
+        # joint fill (scatter-add totals are order-independent, so below the
+        # cap this is bitwise the historical commit).
+        wear_mask = plan.lanes == table_lib.WEAR
+        wear_rows = jnp.concatenate([
+            jnp.where(slow_wr, pipe.frm, 0),
+            jnp.where(wear_mask, plan.rows, 0)])
+        wear_w = jnp.concatenate([
+            slow_wr.astype(jnp.int32),
+            jnp.where(wear_mask, plan.delta, 0)])
+        wear_pre = table[wear_rows, table_lib.WEAR]
+        wear_w = table_lib.saturating_weights(wear_rows, wear_w, wear_pre,
+                                              table_lib.WEAR_CAP)
+        plan_delta = jnp.where(wear_mask, 0, plan.delta)
 
-    idx = jnp.concatenate([
-        page * w_lanes + table_lib.HOTNESS,
-        wear_rows * w_lanes + table_lib.WEAR,
-        plan.rows * w_lanes + plan.lanes,
-        own_idx[None],
-    ])
-    upd = jnp.concatenate([
-        hot_w, wear_w, plan_delta, own_delta[None],
-    ])
-    table = table.reshape(-1).at[idx].add(upd, mode="drop") \
-        .reshape(n_pages, w_lanes)
+    with jax.named_scope("scatter"):
+        idx = jnp.concatenate([
+            page * w_lanes + table_lib.HOTNESS,
+            wear_rows * w_lanes + table_lib.WEAR,
+            plan.rows * w_lanes + plan.lanes,
+            own_idx[None],
+        ])
+        upd = jnp.concatenate([
+            hot_w, wear_w, plan_delta, own_delta[None],
+        ])
+        table = table.reshape(-1).at[idx].add(upd, mode="drop") \
+            .reshape(n_pages, w_lanes)
 
-    do_decay = (sc.chunk_idx % params.decay_every) == (params.decay_every - 1)
-    table = jax.lax.cond(
-        do_decay,
-        lambda t: t.at[:, table_lib.HOTNESS].set(
-            t[:, table_lib.HOTNESS] >> params.hotness_decay_shift),
-        lambda t: t, table)
+    with jax.named_scope("decay"):
+        do_decay = ((sc.chunk_idx % params.decay_every)
+                    == (params.decay_every - 1))
+        table = jax.lax.cond(
+            do_decay,
+            lambda t: t.at[:, table_lib.HOTNESS].set(
+                t[:, table_lib.HOTNESS] >> params.hotness_decay_shift),
+            lambda t: t, table)
+
     # Min-wear scrub: slow frames are rows [0, n_slow) of the WEAR lane.
-    n_slow = n_pages - params.n_fast_pages
-    wmin_global = jnp.min(jnp.where(
-        jnp.arange(n_pages, dtype=jnp.int32) < n_slow,
-        table[:, table_lib.WEAR], 2 ** 30))
-    min_wear = jnp.where(do_decay, wmin_global, sc.min_wear)
+    with jax.named_scope("scrub"):
+        n_slow = n_pages - params.n_fast_pages
+        wmin_global = jnp.min(jnp.where(
+            jnp.arange(n_pages, dtype=jnp.int32) < n_slow,
+            table[:, table_lib.WEAR], 2 ** 30))
+        min_wear = jnp.where(do_decay, wmin_global, sc.min_wear)
     return table, plan.dma, plan.done, now, last_ret, min_wear, \
         plan.tombstone
 
@@ -566,7 +568,7 @@ def policy_phase(cfg: EmulatorConfig, params: RuntimeParams,
 
 
 # --------------------------------------------------------------------------- #
-# the whole step: ref composition + truncated variants for the bench
+# the whole step: ref composition
 # --------------------------------------------------------------------------- #
 
 def step_ref(cfg: EmulatorConfig, registry: PolicyRegistry, table: jax.Array,
@@ -579,6 +581,12 @@ def step_ref(cfg: EmulatorConfig, registry: PolicyRegistry, table: jax.Array,
     the sequential in-kernel recurrences (what the Pallas body runs).
     ``faults`` defaults to the empty plan (bitwise no-op).
 
+    Each phase runs under a named scope (``hmmu.pipeline``,
+    ``hmmu.commit``, ``hmmu.retire``, ``hmmu.policy``) that XLA keeps in
+    every operation's ``op_name`` metadata, so a device trace attributes
+    each operation's time to its phase and stage. Scopes are metadata
+    only: the arithmetic is the same with or without them.
+
     Returns ``(table, scalars, bank_free, outs)`` with ``outs`` carrying
     per-request results (``returns`` masked, ``device`` raw post-redirect,
     ``latency`` masked), the ``held``/``poisoned``/``injected`` counter
@@ -587,25 +595,29 @@ def step_ref(cfg: EmulatorConfig, registry: PolicyRegistry, table: jax.Array,
     """
     if faults is None:
         faults = faults_lib.FaultPlan.empty()
-    pipe = pipeline_phase(cfg, params, table, sc, bank_free,
-                          page, offset, is_write, size, valid, seq=seq)
-    # Transient fault injection: purely observational — the access
-    # completes (the emulated device returned corrupt data); the serving
-    # layer refetches.
-    tc, tp = faults.transient[:, 0], faults.transient[:, 1]
-    injected = ((page[:, None] == tp[None, :]) &
-                (tc[None, :] == sc.chunk_idx)).any(axis=1) & valid
-    table, dma, done, now, last_ret, min_wear, tombstone = commit_phase(
-        cfg, params, table, sc, pipe, page, is_write, valid,
-        eff_write_weight(params, registry))
-    rescue_page = jnp.where(done & (tombstone >= 0), -1,
-                            jnp.asarray(sc.rescue_page, jnp.int32))
-    table, rescue_page, fault_cursor, retired = retire_phase(
-        cfg, params, table, sc, rescue_page,
-        jnp.asarray(sc.fault_cursor, jnp.int32), faults, page, valid)
-    dma, clock_ptr = policy_phase(cfg, params, registry, table, sc, dma, now,
-                                  page, is_write, valid, rescue_page,
-                                  min_wear)
+    with jax.named_scope("hmmu.pipeline"):
+        pipe = pipeline_phase(cfg, params, table, sc, bank_free,
+                              page, offset, is_write, size, valid, seq=seq)
+        # Transient fault injection: purely observational — the access
+        # completes (the emulated device returned corrupt data); the
+        # serving layer refetches.
+        tc, tp = faults.transient[:, 0], faults.transient[:, 1]
+        injected = ((page[:, None] == tp[None, :]) &
+                    (tc[None, :] == sc.chunk_idx)).any(axis=1) & valid
+    with jax.named_scope("hmmu.commit"):
+        table, dma, done, now, last_ret, min_wear, tombstone = commit_phase(
+            cfg, params, table, sc, pipe, page, is_write, valid,
+            eff_write_weight(params, registry))
+    with jax.named_scope("hmmu.retire"):
+        rescue_page = jnp.where(done & (tombstone >= 0), -1,
+                                jnp.asarray(sc.rescue_page, jnp.int32))
+        table, rescue_page, fault_cursor, retired = retire_phase(
+            cfg, params, table, sc, rescue_page,
+            jnp.asarray(sc.fault_cursor, jnp.int32), faults, page, valid)
+    with jax.named_scope("hmmu.policy"):
+        dma, clock_ptr = policy_phase(cfg, params, registry, table, sc, dma,
+                                      now, page, is_write, valid,
+                                      rescue_page, min_wear)
     any_valid = jnp.any(valid)
     sc2 = StepScalars(
         clock=now, clock_ptr=clock_ptr, chunk_idx=sc.chunk_idx + 1, dma=dma,
@@ -618,57 +630,6 @@ def step_ref(cfg: EmulatorConfig, registry: PolicyRegistry, table: jax.Array,
             "held": pipe.held, "poisoned": pipe.poisoned,
             "injected": injected, "retired": retired,
             "tombstone": jnp.asarray(tombstone, jnp.int32)}
-    return table, sc2, pipe.bank_free, outs
-
-
-STAGES = ("rx", "gather", "resolve", "return", "commit", "full")
-
-
-def step_until(cfg: EmulatorConfig, registry: PolicyRegistry,
-               table: jax.Array, params: RuntimeParams, sc: StepScalars,
-               bank_free: jax.Array, page, offset, is_write, size, valid,
-               faults: faults_lib.FaultPlan | None = None, *,
-               upto: str = "full"):
-    """A :func:`step_ref`-shaped step truncated after ``upto`` (one of
-    :data:`STAGES`) — the per-stage breakdown lever of
-    ``benchmarks/bench_chunk_step.py``. Truncated variants keep the carry
-    structure (clock still advances; the retirement registers pass
-    through untouched) so they scan; timing deltas between successive
-    stages isolate each stage's cost."""
-    if upto == "full":
-        return step_ref(cfg, registry, table, params, sc, bank_free,
-                        page, offset, is_write, size, valid, faults)
-    if upto not in STAGES:
-        raise ValueError(f"unknown stage {upto!r}; expected one of {STAGES}")
-    n = page.shape[0]
-    pipe_upto = upto if upto in ("rx", "gather", "resolve") else "full"
-    pipe = pipeline_phase(cfg, params, table, sc, bank_free,
-                          page, offset, is_write, size, valid,
-                          upto=pipe_upto)
-    outs = {"returns": jnp.where(valid, pipe.returns, 0),
-            "device": pipe.dev, "latency": pipe.lat,
-            "held": pipe.held, "poisoned": pipe.poisoned}
-    any_valid = jnp.any(valid)
-    if upto == "commit":
-        table, dma, _, now, last_ret, min_wear, _ = commit_phase(
-            cfg, params, table, sc, pipe, page, is_write, valid,
-            eff_write_weight(params, registry))
-        sc2 = StepScalars(
-            clock=now, clock_ptr=sc.clock_ptr, chunk_idx=sc.chunk_idx + 1,
-            dma=dma,
-            link_free_rx=jnp.where(any_valid, pipe.rx_last, sc.link_free_rx),
-            link_free_tx=jnp.where(any_valid, pipe.tx_last, sc.link_free_tx),
-            last_return=last_ret, rescue_page=sc.rescue_page,
-            min_wear=min_wear, fault_cursor=sc.fault_cursor)
-        return table, sc2, pipe.bank_free, outs
-    sc2 = StepScalars(
-        clock=sc.clock + params.issue_gap * n, clock_ptr=sc.clock_ptr,
-        chunk_idx=sc.chunk_idx + 1, dma=sc.dma,
-        link_free_rx=jnp.where(any_valid, pipe.rx_last, sc.link_free_rx),
-        link_free_tx=jnp.where(any_valid & (pipe_upto == "full"),
-                               pipe.tx_last, sc.link_free_tx),
-        last_return=sc.last_return, rescue_page=sc.rescue_page,
-        min_wear=sc.min_wear, fault_cursor=sc.fault_cursor)
     return table, sc2, pipe.bank_free, outs
 
 
