@@ -8,10 +8,10 @@ of table-aware refinements:
 * **lane-aware table lineage** — a value whose lineage reaches the
   packed ``int32[n_pages, 8]`` table carries *per-lane* intervals, and
   the interpreter tracks lane extraction (row gathers, lane-column
-  gathers, ``slice``/``squeeze``) and lane-targeted scatters (the
-  flattened boundary commit's index arithmetic is tracked modulo 8, so
-  each concatenated section of the ONE commit scatter lands on a known
-  lane);
+  gathers, ``slice``/``squeeze``) and lane-targeted scatters (each
+  concatenated section of the ONE (row, lane) commit scatter lands on
+  the lane its constant lane column names; a flat view's index
+  arithmetic is tracked modulo 8 to the same end);
 * **saturation certificates** — the ``saturating_weights`` idiom in
   core/table.py (``min(max(CAP - pre - psum, 0), w)``) is recognized
   structurally: a scatter-add of certified weights bounds the lane at
@@ -1298,11 +1298,30 @@ class Interp:
                 return int(c.iv[0])
             return None
 
+        def lane_sections():
+            """Sections of a (row, lane) scatter: with a constant lane
+            column (the boundary commit's), one per lane value inside
+            each update piece; else one section on ``col_lane()``."""
+            total = int(np.prod(upd.shape) or 1)
+            row = cols[0].iv
+            lane_of = cols[1].const
+            if lane_of is None or lane_of.size != total:
+                return [(total, AVal((0,), 'i', 32, row, mod=col_lane()),
+                         upd)]
+            lane_of = np.asarray(lane_of).reshape(-1)
+            out, off = [], 0
+            for length, upc in (upd.pieces or [(total, upd)]):
+                seg = lane_of[off:off + length]
+                off += length
+                for lane, cnt in zip(*np.unique(seg, return_counts=True)):
+                    mod = int(lane) if 0 <= lane < 8 else None
+                    out.append((int(cnt), AVal((0,), 'i', 32, row, mod=mod),
+                                upc))
+            return out
+
         if len(a.shape) == 2 and len(cols) == 2:
             # row/lane scatter on the 2-D table
-            secs = [(int(np.prod(upd.shape) or 1),
-                     AVal((0,), 'i', 32, cols[0].iv, mod=col_lane()),
-                     upd)]
+            secs = lane_sections()
         else:
             secs = sections()
         for length, ipc, upc in secs:

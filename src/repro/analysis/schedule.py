@@ -7,15 +7,18 @@ table-copy regression class:
   1. every table *read* (the stage-2 lookup gather, the swap-pair rows,
      the policy's candidate scans) happens against the pre-chunk table or
      the committed table — never against a partially-written copy;
-  2. the chunk's writes collapse into ONE flattened int32 scatter-add
-     (the boundary commit) on the pre-chunk table;
+  2. the chunk's writes collapse into ONE 2-D (row, lane) int32
+     scatter-add (the boundary commit) on the pre-chunk table;
   3. after the commit the only further table writes are the (documented)
      decay cond and the retirement's single-row FLAGS stamp;
-  4. no intermediate whole-table copies exist at all.
+  4. no intermediate whole-table copies exist at all, and the table is
+     never reshaped: a TPU pads each 8-lane row to 128 lanes, so a flat
+     view forces a relayout of the whole padded table.
 
 This pass traces the step with ``jax.make_jaxpr`` and walks the
-equations, tracking the lineage of the table value (reshapes alias,
-writes bump a generation counter). It checks THREE programs:
+equations, tracking the lineage of the table value (writes bump a
+generation counter; a reshape is flagged and then followed as a view).
+It checks THREE programs:
 
   * the scan-path chunk body — the sub-jaxpr of the ``lax.scan`` inside
     ``emulator._emulate_impl`` (what a normal run actually compiles);
@@ -56,7 +59,6 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
     core = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
     tvar = core.invars[table_invar_index]
     tshape = tuple(tvar.aval.shape)
-    flat = (tshape[0] * tshape[1],) if len(tshape) == 2 else tshape
     findings: list[Finding] = []
 
     def bad(eqn, msg):
@@ -77,9 +79,12 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
         g = max(gen[v] for v in ins)
         prim = eqn.primitive.name
         t_outs = [o for o in eqn.outvars
-                  if tuple(getattr(o.aval, "shape", ())) in (tshape, flat)]
-        if prim == "reshape" and t_outs:
-            gen[t_outs[0]] = g  # pure alias (table <-> flat view)
+                  if tuple(getattr(o.aval, "shape", ())) == tshape]
+        if prim == "reshape":
+            bad(eqn, "reshape of the table — on a TPU the 8-lane rows are "
+                     "padded to 128 lanes, so a flat view relays out the "
+                     "whole padded table; scatter on (row, lane) instead")
+            gen[eqn.outvars[0]] = g  # followed as a view of the table
             continue
         if prim == "scatter-add":
             if g == 0:
@@ -89,14 +94,15 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
                              "scatter")
                 else:
                     commit_seen = True
-                    op = eqn.invars[0]
-                    if tuple(op.aval.shape) != flat:
-                        bad(eqn, "boundary commit is not flattened — the "
-                                 "contract is one scatter-add on the "
-                                 "reshape(-1) view")
+                    dims = eqn.params["dimension_numbers"]
+                    if (tuple(eqn.invars[0].aval.shape) != tshape
+                            or tuple(dims.scatter_dims_to_operand_dims)
+                            != tuple(range(len(tshape)))):
+                        bad(eqn, "boundary commit is not a 2-D (row, lane) "
+                                 "scatter-add on the table")
             else:
                 bad(eqn, "extra scatter-add on the committed table")
-            for o in t_outs:
+            for o in eqn.outvars:
                 gen[o] = g + 1
             continue
         if prim in _WRITE_PRIMS:
@@ -156,7 +162,7 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
     if not commit_seen:
         findings.append(Finding(
             f"<{label}>", 0, PASS,
-            f"[{label}] no flattened scatter-add boundary commit found"))
+            f"[{label}] no (row, lane) scatter-add boundary commit found"))
     elif pre_gathers == 0:
         findings.append(Finding(
             f"<{label}>", 0, PASS,

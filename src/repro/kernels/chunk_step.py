@@ -29,13 +29,15 @@ and ``core.emulator`` documents):
    *pre-chunk* table: the stage-2 row gather (chunk pages + DMA swap
    pair), the swap pair's DEVICE/FRAME/EPOCH pre-values consumed by
    ``dma.plan_commit``, and the OWNER pre-value of the promoted frame.
-2. **Boundary commit** — every table write lands in ONE flattened
-   scatter-add over exact int32 deltas (hotness accumulation, demand-
-   write WEAR, the swap commit's lane exchanges, the OWNER inverse-map
-   update routed through a ``mode="drop"`` sentinel), followed by the
-   decay shift. One in-place update instead of ~a dozen copying
-   scatters — the restructure that makes the scan path fast and the
-   kernel possible.
+2. **Boundary commit** — every table write lands in ONE 2-D
+   (row, lane) scatter-add over exact int32 deltas (hotness
+   accumulation, demand-write WEAR, the swap commit's lane exchanges,
+   the OWNER inverse-map update routed through a ``mode="drop"``
+   sentinel row), followed by the decay shift. One in-place update
+   instead of ~a dozen copying scatters — the restructure that makes the
+   scan path fast and the kernel possible. The table is never reshaped
+   to a flat view: a TPU pads each 8-lane row to 128 lanes, so a flat
+   view relays out the whole padded table on the way in and out.
 3. **Retire** — the retirement subsystem (:func:`retire_phase`) reads
    the committed table and stamps at most one dying frame's resident
    page POISONED: a second, sentinel-guarded single-row FLAGS scatter —
@@ -300,11 +302,13 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
                  page, is_write, valid, eff_weight):
     """Commit the chunk to the table: hotness accumulation, demand-write
     WEAR, the DMA swap commit, and the OWNER inverse-map update — all as
-    exact int32 deltas in ONE flattened scatter-add (then the decay
-    shift). Every delta is computed against pre-chunk reads (schedule
-    contract §2), and distinct updates target distinct (row, lane) slots
-    except WEAR, where duplicate targets sum exactly as the historical
-    sequential adds did.
+    exact int32 deltas in ONE 2-D (row, lane) scatter-add on the table
+    (then the decay shift); no flat view, which a TPU would reach by
+    relaying out the table padded to 128 lanes per row. Every delta is
+    computed against pre-chunk reads (schedule contract §2), and
+    distinct updates target distinct (row, lane) slots except WEAR,
+    where duplicate targets sum exactly as the historical sequential
+    adds did.
 
     Retirement extensions (both exactly zero-effect when the subsystem is
     idle): the swap commit's FLAGS triples carry poison travel for the
@@ -320,7 +324,6 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
     clears).
     """
     n = page.shape[0]
-    w_lanes = table.shape[-1]
     n_pages = table.shape[0]
     with jax.named_scope("deltas"):
         any_valid = jnp.any(valid)
@@ -349,15 +352,14 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
                                    params, sc.rescue_page)
         # OWNER inverse map (fast frame -> owning page, the CLOCK victim
         # rotation): the promoted page (swap_a, now FAST) owns its new frame.
-        # No swap completed => route the write through an out-of-range
-        # sentinel dropped by the scatter, so row 0's OWNER lane can never be
-        # clobbered by the idle guard index.
+        # No swap completed => route the write through the out-of-range
+        # sentinel row n_pages, dropped by the scatter, so row 0's OWNER
+        # lane can never be clobbered by the idle guard index.
         db = table_lib.device(pipe.row_b)
         fb = table_lib.frame(pipe.row_b)
         promoted = plan.done & (db == FAST)
         own_pre = table[fb, table_lib.OWNER]
-        own_idx = jnp.where(promoted, fb * w_lanes + table_lib.OWNER,
-                            n_pages * w_lanes)
+        own_row = jnp.where(promoted, fb, n_pages)
         own_delta = jnp.where(promoted, swap_a - own_pre, 0)
 
         # WEAR saturation: demand charges and the swap commit's migration
@@ -380,17 +382,19 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
         plan_delta = jnp.where(wear_mask, 0, plan.delta)
 
     with jax.named_scope("scatter"):
-        idx = jnp.concatenate([
-            page * w_lanes + table_lib.HOTNESS,
-            wear_rows * w_lanes + table_lib.WEAR,
-            plan.rows * w_lanes + plan.lanes,
-            own_idx[None],
-        ])
+        def lane(k, size):
+            return jax.lax.full((size,), k, jnp.int32)
+
+        rows = jnp.concatenate([page, wear_rows, plan.rows, own_row[None]])
+        lanes = jnp.concatenate([
+            lane(table_lib.HOTNESS, n),
+            lane(table_lib.WEAR, wear_rows.shape[0]),
+            plan.lanes,
+            lane(table_lib.OWNER, 1)])
         upd = jnp.concatenate([
             hot_w, wear_w, plan_delta, own_delta[None],
         ])
-        table = table.reshape(-1).at[idx].add(upd, mode="drop") \
-            .reshape(n_pages, w_lanes)
+        table = table.at[rows, lanes].add(upd, mode="drop")
 
     with jax.named_scope("decay"):
         do_decay = ((sc.chunk_idx % params.decay_every)

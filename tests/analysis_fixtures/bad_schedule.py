@@ -7,9 +7,7 @@ non-zero with findings at the lines below."""
 def _bad_step(table, pages, w):
     import jax.numpy as jnp
 
-    flat = table.reshape(-1)
-    committed = flat.at[pages * 8 + 2].add(w, mode="drop")
-    committed = committed.reshape(table.shape)
+    committed = table.at[pages, 2].add(w, mode="drop")
     stale = table[pages, 3]  # stale read of the pre-commit table
     committed = committed.at[pages, 4].add(stale)  # second scatter-add
     return jnp.sum(committed)

@@ -112,9 +112,11 @@ def test_schedule_checker_clean_on_good_step():
 
     def good_step(table, pages, w):
         hot = table[pages, 2]  # gather before the commit
-        flat = table.reshape(-1)
-        t2 = flat.at[pages * 8 + 2].add(w + hot, mode="drop")
-        t2 = t2.reshape(table.shape)
+        rows = jnp.concatenate([pages, pages])
+        lanes = jnp.concatenate([jax.lax.full((4,), 2, i32),
+                                 jax.lax.full((4,), 5, i32)])
+        t2 = table.at[rows, lanes].add(jnp.concatenate([w + hot, w]),
+                                       mode="drop")
         return t2[pages, 3]  # committed-table read
 
     i32 = jnp.int32
@@ -122,6 +124,31 @@ def test_schedule_checker_clean_on_good_step():
         jnp.zeros((16, 8), i32), jnp.arange(4, dtype=i32),
         jnp.ones(4, i32))
     assert schedule.check_jaxpr_schedule(jaxpr, 0, label="good") == []
+
+
+def test_schedule_checker_flags_flattened_commit():
+    """The commit through a flat ``reshape(-1)`` view (the pre-2-D form)
+    is a finding: on a TPU the flat view relays out the padded table."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis import schedule
+
+    def flat_step(table, pages, w):
+        hot = table[pages, 2]
+        flat = table.reshape(-1)
+        t2 = flat.at[pages * 8 + 2].add(w + hot, mode="drop")
+        return t2.reshape(table.shape)[pages, 3]
+
+    i32 = jnp.int32
+    jaxpr = jax.make_jaxpr(flat_step)(
+        jnp.zeros((16, 8), i32), jnp.arange(4, dtype=i32),
+        jnp.ones(4, i32))
+    msgs = [f.message
+            for f in schedule.check_jaxpr_schedule(jaxpr, 0, label="flat")]
+    assert sum("reshape of the table" in m and "128 lanes" in m
+               for m in msgs) == 2, msgs
+    assert any("not a 2-D (row, lane) scatter-add" in m for m in msgs), msgs
 
 
 def test_schedule_checker_flags_missing_commit():
@@ -133,7 +160,38 @@ def test_schedule_checker_flags_missing_commit():
     jaxpr = jax.make_jaxpr(lambda t: t[0, 2])(
         jnp.zeros((16, 8), jnp.int32))
     findings = schedule.check_jaxpr_schedule(jaxpr, 0, label="nocommit")
-    assert any("no flattened scatter-add" in f.message for f in findings)
+    assert any("no (row, lane) scatter-add" in f.message for f in findings)
+
+
+@pytest.mark.parametrize("form", ["row_lane", "flat"])
+def test_ranges_attributes_commit_sections_to_their_lanes(form):
+    """The ranges prover charges each section of the commit scatter to
+    the lane it targets, whether the commit names (row, lane) with a
+    constant lane column or indexes a flat view: an unsaturated add on
+    HOTNESS and WEAR leaves the other lanes proved."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis import ranges
+
+    i32 = jnp.int32
+
+    def step(table, pages, w):
+        upd = jnp.concatenate([jnp.minimum(w, 3)] * 2)
+        if form == "flat":
+            idx = jnp.concatenate([pages * 8 + 2, pages * 8 + 3])
+            return table.reshape(-1).at[idx].add(upd, mode="drop") \
+                .reshape(table.shape)
+        lanes = jnp.concatenate([jax.lax.full((4,), 2, i32),
+                                 jax.lax.full((4,), 3, i32)])
+        return table.at[jnp.concatenate([pages, pages]), lanes].add(
+            upd, mode="drop")
+
+    jaxpr = jax.make_jaxpr(step)(jnp.zeros((16, 8), i32),
+                                 jnp.arange(4, dtype=i32), jnp.ones(4, i32))
+    flagged = {f.message.split()[1]
+               for f in ranges.check_fixture(jaxpr, form)}
+    assert flagged == {"HOTNESS", "WEAR"}
 
 
 def test_donation_aliasing_parser_sees_alias():

@@ -12,6 +12,7 @@ Code that asks ``jax.default_backend()`` still sees the CPU here, so the
 kernel selection is steered by monkeypatching in the test.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,26 +70,33 @@ def _check_default_path(compiled):
     assert used < V5E_HBM_BYTES, f"{used} bytes on a 16 GiB chip"
 
 
-@pytest.mark.parametrize("carried", [False, True], ids=["fresh", "donated"])
-def test_engine_run_compiles_at_paper_geometry(one_chip, carried):
-    """``Engine.run``'s entry point at 294,912 pages, chunk 512: the
-    fresh-state program and the donated continuation."""
+@pytest.fixture(scope="module")
+def run_program(one_chip):
+    """``Engine.run``'s compiled entry point at 294,912 pages, chunk 512,
+    by ``carried``: the fresh-state program or the donated
+    continuation. Each is compiled once for the module."""
     engine = Engine(paper_platform().with_(chunk=512))
     spec = lambda tree: jax.tree.map(lambda x: _spec(x, one_chip), tree)
-    state = spec(jax.eval_shape(engine.init_state)) if carried else None
-    fn = engine._entry_for(N_REQ, carried=carried, donate=carried)
-    compiled = fn.lower(engine._static, engine.registry,
-                        _trace_spec(N_REQ, one_chip),
-                        _valid_spec(N_REQ, one_chip), state,
-                        spec(engine.params), None).compile()
-    _check_default_path(compiled)
-    if carried:
-        assert compiled.memory_analysis().alias_size_in_bytes > 0
+    compiled = {}
+
+    def get(carried):
+        if carried not in compiled:
+            state = (spec(jax.eval_shape(engine.init_state)) if carried
+                     else None)
+            fn = engine._entry_for(N_REQ, carried=carried, donate=carried)
+            compiled[carried] = fn.lower(
+                engine._static, engine.registry,
+                _trace_spec(N_REQ, one_chip), _valid_spec(N_REQ, one_chip),
+                state, spec(engine.params), None).compile()
+        return compiled[carried]
+
+    return get
 
 
-def test_sweep_compiles_16_points_at_paper_geometry(one_chip):
+@pytest.fixture(scope="module")
+def sweep_program(one_chip):
     """The vmapped sweep entry point over the 16-point ``bench_sweep``
-    grid rebased onto the paper geometry: one program for all points."""
+    grid rebased onto the paper geometry, compiled once."""
     from benchmarks.bench_sweep import make_spec
 
     base = paper_platform().with_(chunk=512, hot_threshold=4,
@@ -99,11 +107,63 @@ def test_sweep_compiles_16_points_at_paper_geometry(one_chip):
     assert len(points) == 16
     fn = entry_point(engine._static, registry, batch=True,
                      shape_sig=(N_REQ, 16, True, None, None))
-    compiled = fn.lower(
+    return fn.lower(
         engine._static, registry, _trace_spec(N_REQ, one_chip),
         _valid_spec(N_REQ, one_chip), None,
         jax.tree.map(lambda x: _spec(x, one_chip), params), None).compile()
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["fresh", "donated"])
+def test_engine_run_compiles_at_paper_geometry(run_program, carried):
+    """``Engine.run``'s entry point at 294,912 pages, chunk 512: the
+    fresh-state program and the donated continuation."""
+    compiled = run_program(carried)
     _check_default_path(compiled)
+    if carried:
+        assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_sweep_compiles_16_points_at_paper_geometry(sweep_program):
+    """The vmapped sweep entry point: one program for all points."""
+    _check_default_path(sweep_program)
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\(([^)]*)\)")
+
+
+def _padded_table_relayouts(hlo: str, n_pages: int) -> list[str]:
+    """Names of the ``copy`` and ``reshape`` operations that move the
+    whole table into or out of a row-major layout, in which the chip
+    pads each 8-lane row to 128 lanes: ``s32[n_pages,8]{1,0…}`` or, for
+    a stack of design points, ``s32[P,n_pages,8]{2,1,0…}``."""
+    padded = re.compile(rf"^s32\[(?:{n_pages},8\]\{{1,0|\d+,{n_pages},8\]"
+                        rf"\{{2,1,0)[:}}]")
+    shapes, moves = {}, []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            name, shape, opcode, args = m.groups()
+            shapes[name] = shape
+            if opcode in ("copy", "reshape"):
+                moves.append((name, shape, args.split(",")[0].strip()[1:]))
+    return sorted(name for name, shape, arg in moves
+                  if padded.match(shape) or padded.match(shapes.get(arg, "")))
+
+
+@pytest.mark.parametrize("program,allowed", [("stream", 0), ("sweep", 1)])
+def test_commit_leaves_the_table_unpadded(run_program, sweep_program,
+                                          program, allowed):
+    """The boundary commit is a 2-D (row, lane) scatter-add, so the
+    carried table is never relaid out to its padded row-major form
+    around it (four passes of the padded table per chunk when the
+    commit scattered through a flat view). The donated run has no such
+    operation. The sweep keeps one: the retirement stamp's FLAGS
+    scatter copies the stacked table (PERF.md, bottleneck (2))."""
+    compiled = (run_program(True) if program == "stream"
+                else sweep_program)
+    found = _padded_table_relayouts(compiled.as_text(),
+                                    paper_platform().n_pages)
+    assert len(found) <= allowed, found
 
 
 def test_tpu_selection_rule(monkeypatch):
