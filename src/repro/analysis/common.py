@@ -236,10 +236,13 @@ def trace_step_ref(cfg, registry, seq: bool, *,
     return jaxpr, names, fixed
 
 
-def scan_body_info(cfg, registry):
+def scan_body_info(cfg, registry, *, flat: bool | None = None):
     """The chunk body of the compiled scan path, with enough structure to
     map its invars: trace ``_emulate_impl`` (params concrete, faults a
-    shaped traced input) and pull the ``scan`` equation.
+    shaped traced input) and pull the ``scan`` equation. ``flat`` forces
+    the carried table form (``emulator.FLAT_CARRY_MIN_BYTES`` moved to
+    either side of the table while tracing); None traces the form
+    ``cfg``'s table size selects.
 
     Returns ``(info, err)`` where info is a dict with:
 
@@ -251,7 +254,9 @@ def scan_body_info(cfg, registry):
     * ``carry_names``: dotted ``EmulatorState`` leaf labels for body
       invars ``[num_consts : num_consts + num_carry]`` (flattening order
       of the carry pytree is the flattening order of the state);
-    * ``table_index``: body invar index of the packed table carry.
+    * ``table_index``: body invar index of the table carry, in the form
+      the scan carries it (packed ``[n_pages, 8]`` or flat lane-major
+      ``[8 * n_pages]``).
     """
     import jax
     import jax.numpy as jnp
@@ -272,7 +277,13 @@ def scan_body_info(cfg, registry):
     def fn(trace, faults):
         return emu._emulate_impl(cfg, registry, trace, faults=faults)
 
-    outer = jax.make_jaxpr(fn)(trace, faults)
+    threshold = emu.FLAT_CARRY_MIN_BYTES
+    if flat is not None:
+        emu.FLAT_CARRY_MIN_BYTES = 0 if flat else 2**62
+    try:
+        outer = jax.make_jaxpr(fn)(trace, faults)
+    finally:
+        emu.FLAT_CARRY_MIN_BYTES = threshold
     outer_names = _leaf_names("trace", trace) + _leaf_names("faults", faults)
     scans = [e for e in outer.jaxpr.eqns if e.primitive.name == "scan"]
     if not scans:
@@ -286,12 +297,12 @@ def scan_body_info(cfg, registry):
         return None, (f"scan carries {num_carry} leaves but EmulatorState "
                       f"flattens to {len(carry_names)} — the carry mapping "
                       "needs retargeting")
-    tshape = (cfg.n_pages, 8)
+    tshapes = ((cfg.n_pages, 8), (8 * cfg.n_pages,))
     idx = [i for i, v in enumerate(body.invars)
-           if tuple(v.aval.shape) == tshape]
+           if tuple(v.aval.shape) in tshapes]
     if len(idx) != 1:
-        return None, (f"expected exactly one {tshape} carry in the scan "
-                      f"body, found {len(idx)}")
+        return None, (f"expected exactly one table carry {tshapes} in the "
+                      f"scan body, found {len(idx)}")
     return {"outer": outer, "outer_names": outer_names, "scan_eqn": eqn,
             "body": body, "num_consts": num_consts, "num_carry": num_carry,
             "carry_names": carry_names, "table_index": idx[0]}, None

@@ -7,21 +7,28 @@ table-copy regression class:
   1. every table *read* (the stage-2 lookup gather, the swap-pair rows,
      the policy's candidate scans) happens against the pre-chunk table or
      the committed table — never against a partially-written copy;
-  2. the chunk's writes collapse into ONE 2-D (row, lane) int32
-     scatter-add (the boundary commit) on the pre-chunk table;
+  2. the chunk's writes collapse into ONE int32 scatter-add (the
+     boundary commit) on the pre-chunk table, over every axis of the
+     table in the form it is carried: 2-D (row, lane) on the packed
+     ``[n_pages, 8]`` table, 1-D on the flat lane-major form the scan
+     carries (``core.table``);
   3. after the commit the only further table writes are the (documented)
      decay cond and the retirement's single-row FLAGS stamp;
-  4. no intermediate whole-table copies exist at all, and the table is
-     never reshaped: a TPU pads each 8-lane row to 128 lanes, so a flat
-     view forces a relayout of the whole padded table.
+  4. no intermediate whole-table copies exist at all, and the step never
+     reshapes the table: on a TPU a reshape relays out the whole table
+     (the packed form pads each 8-lane row to 128 lanes). The one
+     conversion between the forms happens in ``_emulate_impl`` around
+     the scan, once per call, outside the body this pass checks.
 
 This pass traces the step with ``jax.make_jaxpr`` and walks the
 equations, tracking the lineage of the table value (writes bump a
 generation counter; a reshape is flagged and then followed as a view).
-It checks THREE programs:
+It checks FOUR programs:
 
   * the scan-path chunk body — the sub-jaxpr of the ``lax.scan`` inside
-    ``emulator._emulate_impl`` (what a normal run actually compiles);
+    ``emulator._emulate_impl`` (what a normal run actually compiles),
+    once with the table carried packed and once flat (the form a table
+    of ``emulator.FLAT_CARRY_MIN_BYTES`` or more takes);
   * ``step_ref(..., seq=True)`` — the literal Pallas kernel body
     (``_pallas_step_fn._body`` calls it; an AST check below pins that
     link so tracing ``seq=True`` IS checking the kernel);
@@ -37,6 +44,7 @@ import ast
 import pathlib
 
 from .common import Finding, eqn_loc, rel, scan_body_info, trace_step_ref
+from repro.core.table import ROW_W
 
 from jax.extend.core import Literal, Var
 
@@ -81,9 +89,11 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
         t_outs = [o for o in eqn.outvars
                   if tuple(getattr(o.aval, "shape", ())) == tshape]
         if prim == "reshape":
-            bad(eqn, "reshape of the table — on a TPU the 8-lane rows are "
-                     "padded to 128 lanes, so a flat view relays out the "
-                     "whole padded table; scatter on (row, lane) instead")
+            bad(eqn, "reshape of the table inside the step — on a TPU a "
+                     "reshape relays out the whole table (the packed "
+                     "form's 8-lane rows are padded to 128 lanes); carry "
+                     "the form the scatter works on and convert once per "
+                     "call, outside the scan")
             gen[eqn.outvars[0]] = g  # followed as a view of the table
             continue
         if prim == "scatter-add":
@@ -99,7 +109,8 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
                             or tuple(dims.scatter_dims_to_operand_dims)
                             != tuple(range(len(tshape)))):
                         bad(eqn, "boundary commit is not a 2-D (row, lane) "
-                                 "scatter-add on the table")
+                                 "scatter-add on the packed table nor a "
+                                 "1-D one on the flat carried form")
             else:
                 bad(eqn, "extra scatter-add on the committed table")
             for o in eqn.outvars:
@@ -115,7 +126,7 @@ def check_jaxpr_schedule(jaxpr, table_invar_index: int = 0,
                 n_upd = 1
                 for d in getattr(upd.aval, "shape", ()):
                     n_upd *= d
-                if n_upd > tshape[-1]:
+                if n_upd > ROW_W:
                     bad(eqn, f"post-commit `{prim}` larger than one table "
                              "row — only the retirement's single-row FLAGS "
                              "stamp may follow the commit")
@@ -177,10 +188,11 @@ def _trace_step_ref(cfg, registry, seq: bool):
     return jaxpr
 
 
-def _scan_body_jaxpr(cfg, registry):
-    """The chunk body of the compiled scan path (via
-    :func:`common.scan_body_info`) as ``((body, table_index), err)``."""
-    info, err = scan_body_info(cfg, registry)
+def _scan_body_jaxpr(cfg, registry, flat: bool):
+    """The chunk body of the compiled scan path with the table carried
+    ``flat`` or packed (via :func:`common.scan_body_info`) as ``((body,
+    table_index), err)``."""
+    info, err = scan_body_info(cfg, registry, flat=flat)
     if err is not None:
         return None, err
     return (info["body"], info["table_index"]), None
@@ -221,12 +233,13 @@ def run_repo(root: pathlib.Path) -> list[Finding]:
     cfg = small_platform()
     registry = as_registry(None)
     findings = _check_pallas_body_link(root)
-    body, err = _scan_body_jaxpr(cfg, registry)
-    if err is not None:
-        findings.append(Finding("src/repro/core/emulator.py", 1, PASS, err))
-    else:
-        findings += check_jaxpr_schedule(body[0], body[1],
-                                         label="scan-path")
+    for flat, label in ((False, "scan-path"), (True, "scan-path-flat")):
+        body, err = _scan_body_jaxpr(cfg, registry, flat)
+        if err is not None:
+            findings.append(Finding("src/repro/core/emulator.py", 1, PASS,
+                                    err))
+        else:
+            findings += check_jaxpr_schedule(body[0], body[1], label=label)
     findings += check_jaxpr_schedule(
         _trace_step_ref(cfg, registry, seq=True), 0, label="pallas-body")
     findings += check_jaxpr_schedule(

@@ -227,18 +227,18 @@ def maybe_complete(cfg: EmulatorConfig, dma: DMAState, now: jax.Array,
     Returns (state, table, done_flag)."""
     ia = jnp.maximum(dma.page_a, 0)
     ib = jnp.maximum(dma.page_b, 0)
-    plan = plan_commit(cfg, dma, now, table[ia], table[ib], params,
-                       rescue_page)
+    plan = plan_commit(cfg, dma, now, table_lib.rows(table, ia),
+                       table_lib.rows(table, ib), params, rescue_page)
     # WEAR deltas saturate at WEAR_CAP like the chunk-boundary commit
     # (at most one WEAR charge per commit — a swap always pairs FAST with
     # SLOW — so a plain min against the headroom is exact).
-    pre = table[plan.rows, plan.lanes]
+    pre = table_lib.lane_at(table, plan.rows, plan.lanes)
     delta = jnp.where(
         plan.lanes == table_lib.WEAR,
         jnp.minimum(plan.delta,
                     jnp.maximum(jnp.int32(table_lib.WEAR_CAP) - pre, 0)),
         plan.delta)
-    table = table.at[plan.rows, plan.lanes].add(delta)
+    table = table_lib.add_at(table, plan.rows, plan.lanes, delta)
     return plan.dma, table, plan.done
 
 

@@ -171,6 +171,25 @@ def _chunk_step(cfg: EmulatorConfig, params: RuntimeParams,
     return new_state, out
 
 
+# Table bytes (per design point) from which the scan carries the flat
+# lane-major form. On a TPU, XLA's scatter works on that form, so a packed
+# carry is relaid out around the commit scatter of every chunk, at a cost
+# that grows faster than the table. The flat carry costs more index work
+# in a latency-bound step instead. On a TPU v5e (PERF.md, section 6), us
+# per chunk packed / flat: 345 / 374 at 9.4 MB, 357 / 484 at 14.2 MB,
+# 666 / 539 at 18.9 MB, 63,590 / 983 at 1.34 GB.
+FLAT_CARRY_MIN_BYTES = 16 * 2**20
+
+
+def carries_flat_table(cfg: EmulatorConfig) -> bool:
+    """Whether the scan carries the table in the flat lane-major form
+    (``core.table``), converted once per call on each side of the scan,
+    rather than packed: by table size, and never for the Pallas step,
+    which reads the packed form."""
+    return (cfg.n_pages * table_lib.ROW_W * 4 >= FLAT_CARRY_MIN_BYTES
+            and not chunk_step_lib.use_chunk_step_kernel(cfg))
+
+
 def _emulate_impl(cfg: EmulatorConfig, registry: PolicyRegistry, trace: Trace,
                   valid: jax.Array | None = None,
                   state: EmulatorState | None = None,
@@ -189,9 +208,14 @@ def _emulate_impl(cfg: EmulatorConfig, registry: PolicyRegistry, trace: Trace,
         state = init_state(cfg, params)
     chunks = jax.tree.map(lambda x: x.reshape(n // cfg.chunk, cfg.chunk),
                           (trace, valid))
+    flat = carries_flat_table(cfg)
+    if flat:
+        state = state._replace(table=table_lib.to_flat(state.table))
     state, outs = jax.lax.scan(
         functools.partial(_chunk_step, cfg, params, registry, faults), state,
         chunks, unroll=cfg.scan_unroll)
+    if flat:
+        state = state._replace(table=table_lib.to_packed(state.table))
     outs = jax.tree.map(lambda x: x.reshape(n), outs)
     return state, outs
 

@@ -31,8 +31,10 @@ trace time and passes the maintained global min-wear register
 unchanged.
 
 ``cfg`` carries static geometry, ``params`` the traced knobs
-(``hot_threshold``, ``n_fast_pages``, ...), ``table`` the packed
-``int32[n_pages, ROW_W]`` metadata store. New policies register via
+(``hot_threshold``, ``n_fast_pages``, ...), ``table`` the metadata store
+in either of its forms (the packed ``int32[n_pages, ROW_W]``, or the flat
+lane-major form the emulator's scan carries): policies read it only
+through ``core.table``'s accessors, which take both. New policies register via
 ``@register("name")``; the emulator dispatches on the traced
 ``params.policy_id`` with ``jax.lax.switch`` over the registration order,
 which makes the policy itself a batchable design axis (sweeps evaluate
@@ -179,7 +181,7 @@ def _chunk_candidate(table, pages, valid, extra_mask=None):
     anyway, and a vetoed hottest page would livelock the proposal stream.
     ``extra_mask`` further restricts eligibility (wear_level's
     destination freshness)."""
-    rows = table[pages]
+    rows = table_lib.rows(table, pages)
     ok = valid & (table_lib.device(rows) == SLOW) & \
         ~table_lib.is_pinned(rows) & ~table_lib.is_retired(rows)
     if extra_mask is not None:
@@ -213,8 +215,8 @@ def _clock_victim(table, ptr, nf):
     and the arithmetic reduces exactly to the classic ``ptr + want``."""
     offs = jnp.arange(_CLOCK_WINDOW, dtype=jnp.int32)
     frames = (ptr + offs) % nf
-    owners = table_lib.owner(table)[frames]
-    rows = table[owners]
+    owners = table_lib.lane_at(table, frames, table_lib.OWNER)
+    rows = table_lib.rows(table, owners)
     pinned = table_lib.is_pinned(rows) | table_lib.is_retired(rows)
     first = jnp.argmin(pinned).astype(jnp.int32)   # first False, else 0
     found = ~pinned[first]
@@ -274,8 +276,8 @@ def stream_policy(cfg, params, table, ptr, pages, is_write, valid):
     streaming = strength > (pages.shape[0] // 4)
 
     last = pages[jnp.argmax(jnp.where(valid, jnp.arange(pages.shape[0]), -1))]
-    target = jnp.clip(last + stride, 0, table.shape[0] - 1)
-    target_row = table[target]
+    target = jnp.clip(last + stride, 0, table_lib.n_pages(table) - 1)
+    target_row = table_lib.rows(table, target)
     target_is_slow = (table_lib.device(target_row) == SLOW) & \
         ~table_lib.is_pinned(target_row) & ~table_lib.is_retired(target_row)
 
@@ -294,9 +296,10 @@ def hotness_global_policy(cfg, params, table, ptr, pages, is_write, valid):
     """Idealized reference: global hottest-slow / coldest-fast scan each
     chunk. No RTL implements this in a cycle — kept for design-space
     comparison against the realizable policies above."""
-    dev = table_lib.device(table)
-    hot = table_lib.hotness(table)
-    pinned = table_lib.is_pinned(table) | table_lib.is_retired(table)
+    dev = table_lib.column(table, table_lib.DEVICE)
+    hot = table_lib.column(table, table_lib.HOTNESS)
+    flg = table_lib.column(table, table_lib.FLAGS)
+    pinned = (flg & (table_lib.PINNED | table_lib.RETIRED)) != 0
     heat_all = jnp.where((dev == SLOW) & ~pinned, hot, -1)
     cand = jnp.argmax(heat_all).astype(jnp.int32)
     heat = heat_all[cand]
@@ -330,7 +333,7 @@ def wear_level_policy(cfg, params, table, ptr, pages, is_write, valid,
     histogram's* floor; policies invoked outside the emulator (tests,
     notebooks) may pass ``min_wear=None`` to fall back to the historical
     chunk-local floor over this chunk's slow frames."""
-    rows = table[pages]
+    rows = table_lib.rows(table, pages)
     slow = valid & (table_lib.device(rows) == SLOW)
     frm = table_lib.frame(rows)
     # WEAR is keyed by slow frame: one O(chunk) gather of the candidates'

@@ -53,6 +53,22 @@ reuse the same rows keyed by frame number (frames < n_pages always).
 Policies, the DMA engine and the counters read named lanes through the
 accessors below — never raw column indices.
 
+Two forms of one table. ``EmulatorState.table`` is always the packed
+``int32[n_pages, ROW_W]`` form above. For a table of
+``emulator.FLAT_CARRY_MIN_BYTES`` or more, the emulator's chunk scan
+carries the *flat lane-major* form ``int32[ROW_W * n_pages]`` instead —
+element (page, lane) at ``lane * n_pages + page`` — converted once per
+call on each side of the scan (:func:`to_flat` / :func:`to_packed`).
+That is the form XLA's TPU scatter works on, so the boundary commit's
+scatter-add no longer relays out the whole table around itself on every
+chunk; a whole lane is one contiguous slice. This module is the only
+one that knows either layout: the table-level accessors (:func:`n_pages`,
+:func:`rows`, :func:`lane_at`, :func:`column`, :func:`add_at`,
+:func:`set_at`, :func:`set_column`) take either form and decide by its
+rank, and return the same values for both. The row accessors
+(:func:`device`, :func:`frame`, :func:`flags`, :func:`is_pinned`, …)
+read gathered rows ``[..., ROW_W]`` (and whole packed tables).
+
 The paper's middleware (mem_driver.ko + modified jemalloc, §III-G) becomes
 ``HybridAllocator``: a host-side page allocator over the flat space that
 honours placement *hints* (the paper's extended malloc API) by choosing
@@ -158,32 +174,132 @@ def is_retired(table: jax.Array) -> jax.Array:
     return (table[..., FLAGS] & RETIRED) != 0
 
 
+def is_flat(table: jax.Array) -> bool:
+    """True for the flat lane-major form the chunk scan carries (rank 1),
+    False for the packed ``[n_pages, ROW_W]`` form (rank 2)."""
+    return table.ndim == 1
+
+
+def n_pages(table: jax.Array) -> int:
+    """Rows (pages) in a table of either form."""
+    return table.shape[0] // ROW_W if is_flat(table) else table.shape[0]
+
+
+def to_flat(table: jax.Array) -> jax.Array:
+    """Packed ``[n_pages, ROW_W]`` -> flat lane-major ``[ROW_W * n_pages]``
+    (lane ``k`` is the contiguous slice ``[k * n_pages, (k+1) * n_pages)``).
+    The index stays int32: 8 x 41,943,040 pages is still below 2**31."""
+    return table.T.reshape(-1)
+
+
+def to_packed(table: jax.Array) -> jax.Array:
+    """Inverse of :func:`to_flat`."""
+    return table.reshape(ROW_W, -1).T
+
+
+def _gather_index(table: jax.Array, idx) -> jax.Array:
+    """Row indices under jnp's gather rule (negative indices count from
+    the end, then clamp to the table), so both forms read the same rows
+    for any index."""
+    n = n_pages(table)
+    idx = jnp.asarray(idx, jnp.int32)
+    return jax.lax.clamp(0, jax.lax.select(idx < 0, idx + n, idx), n - 1)
+
+
+def _scatter_index(table: jax.Array, idx, lane) -> jax.Array:
+    """Flat indices of (row, lane) write targets. A row outside
+    ``[0, n_pages)`` (after jnp's negative-index rule) — the commit's
+    "no write" sentinel row ``n_pages`` above all — maps to ``ROW_W *
+    n_pages``, past the end, so ``mode="drop"`` drops it. Left as
+    ``lane * n_pages + row`` it would land on row 0 of the next lane."""
+    n = n_pages(table)
+    idx = jnp.asarray(idx, jnp.int32)
+    idx = jax.lax.select(idx < 0, idx + n, idx)
+    flat = jnp.asarray(lane, jnp.int32) * n + idx
+    inside = jnp.broadcast_to((idx >= 0) & (idx < n), flat.shape)
+    return jax.lax.select(inside, flat, jnp.full_like(flat, ROW_W * n))
+
+
+def rows(table: jax.Array, idx) -> jax.Array:
+    """Whole rows ``[..., ROW_W]`` at row indices ``idx`` (jnp's gather
+    rule: negative from the end, then clamped). On the flat form, one
+    gather over an index array ``lane * n_pages + idx[..., None]``."""
+    if not is_flat(table):
+        return table[idx]
+    lanes = jnp.arange(ROW_W, dtype=jnp.int32) * n_pages(table)
+    return table[lanes + _gather_index(table, idx)[..., None]]
+
+
+def lane_at(table: jax.Array, idx, lane) -> jax.Array:
+    """One lane of rows ``idx`` (``lane`` a constant or an array aligned
+    with ``idx``) as a single-lane gather, no full-row fetch."""
+    if not is_flat(table):
+        return table[idx, lane]
+    return table[jnp.asarray(lane, jnp.int32) * n_pages(table)
+                 + _gather_index(table, idx)]
+
+
+def column(table: jax.Array, lane: int) -> jax.Array:
+    """The whole ``lane`` (a constant) as ``int32[n_pages]``: a contiguous
+    slice of the flat form."""
+    if not is_flat(table):
+        return table[:, lane]
+    n = n_pages(table)
+    return jax.lax.slice(table, (lane * n,), ((lane + 1) * n,))
+
+
+def set_column(table: jax.Array, lane: int, values) -> jax.Array:
+    """Replace the whole ``lane`` with ``values`` (one in-place update of
+    a contiguous slice on the flat form)."""
+    if not is_flat(table):
+        return table.at[:, lane].set(values)
+    return jax.lax.dynamic_update_slice(
+        table, jnp.asarray(values, table.dtype), (lane * n_pages(table),))
+
+
+def add_at(table: jax.Array, idx, lane, upd) -> jax.Array:
+    """Scatter-add ``upd`` at (row, lane) targets — ONE scatter. Rows
+    outside the table drop (the sentinel-row convention)."""
+    if not is_flat(table):
+        return table.at[idx, lane].add(upd, mode="drop")
+    return table.at[_scatter_index(table, idx, lane)].add(upd, mode="drop")
+
+
+def set_at(table: jax.Array, idx, lane, values) -> jax.Array:
+    """Store ``values`` at (row, lane) targets; rows outside the table
+    drop."""
+    if not is_flat(table):
+        return table.at[idx, lane].set(values, mode="drop")
+    return table.at[_scatter_index(table, idx, lane)].set(values,
+                                                          mode="drop")
+
+
 def device_at(table: jax.Array, pages) -> jax.Array:
     """DEVICE lane of ``pages`` as a single-lane gather (no full-row
     fetch) — the read the stamp/veto paths use."""
-    return table[pages, DEVICE]
+    return lane_at(table, pages, DEVICE)
 
 
 def hotness_at(table: jax.Array, pages) -> jax.Array:
     """HOTNESS lane of ``pages`` as a single-lane gather."""
-    return table[pages, HOTNESS]
+    return lane_at(table, pages, HOTNESS)
 
 
 def wear_at(table: jax.Array, frames) -> jax.Array:
     """WEAR lane of ``frames`` (WEAR is keyed by slow frame) as a
     single-lane gather."""
-    return table[frames, WEAR]
+    return lane_at(table, frames, WEAR)
 
 
 def flags_at(table: jax.Array, pages) -> jax.Array:
     """FLAGS lane of ``pages`` as a single-lane gather."""
-    return table[pages, FLAGS]
+    return lane_at(table, pages, FLAGS)
 
 
 def add_hotness(table: jax.Array, pages, w) -> jax.Array:
     """Scatter-add access weights into the HOTNESS lane (out-of-range
     pages drop — the sentinel-index convention of the boundary commit)."""
-    return table.at[pages, HOTNESS].add(w, mode="drop")
+    return add_at(table, pages, HOTNESS, w)
 
 
 def saturating_weights(targets: jax.Array, weights: jax.Array,
@@ -215,8 +331,9 @@ def saturating_weights(targets: jax.Array, weights: jax.Array,
 
 
 def decay_hotness(table: jax.Array, shift) -> jax.Array:
-    """The aging tick: arithmetic-shift every page's HOTNESS lane."""
-    return table.at[:, HOTNESS].set(table[:, HOTNESS] >> shift)
+    """The aging tick: arithmetic-shift every page's HOTNESS lane (one
+    lane update, in place on the flat form)."""
+    return set_column(table, HOTNESS, column(table, HOTNESS) >> shift)
 
 
 def store_flags(table: jax.Array, idx, values) -> jax.Array:
@@ -224,7 +341,7 @@ def store_flags(table: jax.Array, idx, values) -> jax.Array:
     sentinel rows drop). The traced counterpart of
     :func:`set_flags`/:func:`clear_flags` for batched stamp programs that
     compute the new FLAGS words themselves."""
-    return table.at[idx, FLAGS].set(values, mode="drop")
+    return set_at(table, idx, FLAGS, values)
 
 
 def swap_commit_lanes(k: jax.Array) -> jax.Array:
@@ -245,15 +362,15 @@ def set_flags(table: jax.Array, pages, bits: int) -> jax.Array:
     """OR ``bits`` into the FLAGS lane of ``pages`` (scenario/middleware
     side — the hot path never writes FLAGS)."""
     pages = jnp.asarray(pages, jnp.int32)
-    cur = table[pages, FLAGS]
-    return table.at[pages, FLAGS].set(cur | jnp.int32(bits))
+    cur = flags_at(table, pages)
+    return set_at(table, pages, FLAGS, cur | jnp.int32(bits))
 
 
 def clear_flags(table: jax.Array, pages, bits: int = KNOWN_FLAGS) -> jax.Array:
     """Clear ``bits`` (default: all known bits) on ``pages``."""
     pages = jnp.asarray(pages, jnp.int32)
-    cur = table[pages, FLAGS]
-    return table.at[pages, FLAGS].set(cur & ~jnp.int32(bits))
+    cur = flags_at(table, pages)
+    return set_at(table, pages, FLAGS, cur & ~jnp.int32(bits))
 
 
 def pack_rows(device, frame, hotness=None, wear=None, owner=None,

@@ -29,15 +29,17 @@ and ``core.emulator`` documents):
    *pre-chunk* table: the stage-2 row gather (chunk pages + DMA swap
    pair), the swap pair's DEVICE/FRAME/EPOCH pre-values consumed by
    ``dma.plan_commit``, and the OWNER pre-value of the promoted frame.
-2. **Boundary commit** — every table write lands in ONE 2-D
-   (row, lane) scatter-add over exact int32 deltas (hotness
-   accumulation, demand-write WEAR, the swap commit's lane exchanges,
-   the OWNER inverse-map update routed through a ``mode="drop"``
-   sentinel row), followed by the decay shift. One in-place update
-   instead of ~a dozen copying scatters — the restructure that makes the
-   scan path fast and the kernel possible. The table is never reshaped
-   to a flat view: a TPU pads each 8-lane row to 128 lanes, so a flat
-   view relays out the whole padded table on the way in and out.
+2. **Boundary commit** — every table write lands in ONE scatter-add
+   over exact int32 (row, lane) deltas (hotness accumulation,
+   demand-write WEAR, the swap commit's lane exchanges, the OWNER
+   inverse-map update routed through a ``mode="drop"`` sentinel row),
+   followed by the decay shift. One in-place update instead of ~a dozen
+   copying scatters — the restructure that makes the scan path fast and
+   the kernel possible. The scatter is 2-D on the packed table and 1-D
+   on the flat lane-major form (``core.table``) that the scan carries
+   for a large table: the form XLA's TPU scatter works on, so nothing
+   relays the table out around it. The step never reshapes the table;
+   the emulator converts between the forms once per call.
 3. **Retire** — the retirement subsystem (:func:`retire_phase`) reads
    the committed table and stamps at most one dying frame's resident
    page POISONED: a second, sentinel-guarded single-row FLAGS scatter —
@@ -81,6 +83,7 @@ from repro.core import table as table_lib
 from repro.core.config import FAST, SLOW, EmulatorConfig, RuntimeParams
 from repro.core.policies import PolicyRegistry
 from . import ops as kernel_ops
+from . import ref as kernel_ref
 
 # Python literals, NOT eager jnp arrays: everything below also traces
 # inside the Pallas body, which rejects captured device constants.
@@ -219,10 +222,12 @@ def pipeline_phase(cfg: EmulatorConfig, params: RuntimeParams,
         arrive = rx_done + jnp.where(valid, params.link_lat // 2, 0)
 
     # --- stage 2: redirection-table lookup (+ DMA swap-progress redirect).
-    # One packed-row fetch — the BRAM read per cycle of the paper's
-    # pipeline. The scan path goes through the batched lookup engine
-    # (Pallas gather on TPU, jnp elsewhere; the fused flavour appends the
-    # DMA swap pair, chunk + 2 rows in one launch). Inside the one-kernel
+    # One row fetch per request — the BRAM read per cycle of the paper's
+    # pipeline. The flat lane-major form (a large table's scan carry) is
+    # gathered through one index array [k, ROW_W] (the fused flavour
+    # appends the DMA swap pair, chunk + 2 rows in one gather). A packed
+    # table goes through the batched lookup engine (Pallas gather where
+    # selected, jnp elsewhere). Inside the one-kernel
     # body the table is already VMEM-resident, so the gather is a direct
     # row index. All paths clamp indices identically.
     with jax.named_scope("lookup"):
@@ -232,13 +237,23 @@ def pipeline_phase(cfg: EmulatorConfig, params: RuntimeParams,
             pg = jnp.clip(page, 0, table.shape[0] - 1)
             rows = table[pg]
             row_a, row_b = table[a], table[b]
+        elif table_lib.is_flat(table):
+            pg = jnp.clip(page, 0, table_lib.n_pages(table) - 1)
+            swap = jnp.stack([a, b])
+            if cfg.fuse_swap_gather:
+                rows, swap_rows = kernel_ref.fused_gather(
+                    table_lib.rows, table, pg, swap)
+            else:
+                rows = table_lib.rows(table, pg)
+                swap_rows = table_lib.rows(table, swap)
+            row_a, row_b = swap_rows[0], swap_rows[1]
         elif cfg.fuse_swap_gather:
             rows, swap_rows = kernel_ops.hmmu_lookup_fused(
                 table, page, jnp.stack([a, b]))
             row_a, row_b = swap_rows[..., 0, :], swap_rows[..., 1, :]
         else:
             rows = kernel_ops.hmmu_lookup(table, page)
-            row_a, row_b = table[a], table[b]
+            row_a, row_b = table_lib.rows(table, a), table_lib.rows(table, b)
         dev = table_lib.device(rows)
         frm = table_lib.frame(rows)
         hot_pre = table_lib.hotness(rows)
@@ -302,9 +317,11 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
                  page, is_write, valid, eff_weight):
     """Commit the chunk to the table: hotness accumulation, demand-write
     WEAR, the DMA swap commit, and the OWNER inverse-map update — all as
-    exact int32 deltas in ONE 2-D (row, lane) scatter-add on the table
-    (then the decay shift); no flat view, which a TPU would reach by
-    relaying out the table padded to 128 lanes per row. Every delta is
+    exact int32 deltas in ONE scatter-add on the table in the form it is
+    carried (``table_lib.add_at``: 2-D (row, lane) on the packed form, 1-D
+    on the flat lane-major form), then the decay shift. The step never
+    reshapes the table: on a TPU a reshape relays out the whole table
+    (padded to 128 lanes per row in the packed form). Every delta is
     computed against pre-chunk reads (schedule contract §2), and
     distinct updates target distinct (row, lane) slots except WEAR,
     where duplicate targets sum exactly as the historical sequential
@@ -324,7 +341,7 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
     clears).
     """
     n = page.shape[0]
-    n_pages = table.shape[0]
+    n_pages = table_lib.n_pages(table)
     with jax.named_scope("deltas"):
         any_valid = jnp.any(valid)
         last_ret = jnp.where(
@@ -358,7 +375,7 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
         db = table_lib.device(pipe.row_b)
         fb = table_lib.frame(pipe.row_b)
         promoted = plan.done & (db == FAST)
-        own_pre = table[fb, table_lib.OWNER]
+        own_pre = table_lib.lane_at(table, fb, table_lib.OWNER)
         own_row = jnp.where(promoted, fb, n_pages)
         own_delta = jnp.where(promoted, swap_a - own_pre, 0)
 
@@ -376,7 +393,7 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
         wear_w = jnp.concatenate([
             slow_wr.astype(jnp.int32),
             jnp.where(wear_mask, plan.delta, 0)])
-        wear_pre = table[wear_rows, table_lib.WEAR]
+        wear_pre = table_lib.lane_at(table, wear_rows, table_lib.WEAR)
         wear_w = table_lib.saturating_weights(wear_rows, wear_w, wear_pre,
                                               table_lib.WEAR_CAP)
         plan_delta = jnp.where(wear_mask, 0, plan.delta)
@@ -394,15 +411,15 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
         upd = jnp.concatenate([
             hot_w, wear_w, plan_delta, own_delta[None],
         ])
-        table = table.at[rows, lanes].add(upd, mode="drop")
+        table = table_lib.add_at(table, rows, lanes, upd)
 
     with jax.named_scope("decay"):
         do_decay = ((sc.chunk_idx % params.decay_every)
                     == (params.decay_every - 1))
         table = jax.lax.cond(
             do_decay,
-            lambda t: t.at[:, table_lib.HOTNESS].set(
-                t[:, table_lib.HOTNESS] >> params.hotness_decay_shift),
+            lambda t: table_lib.decay_hotness(t,
+                                              params.hotness_decay_shift),
             lambda t: t, table)
 
     # Min-wear scrub: slow frames are rows [0, n_slow) of the WEAR lane.
@@ -410,7 +427,7 @@ def commit_phase(cfg: EmulatorConfig, params: RuntimeParams,
         n_slow = n_pages - params.n_fast_pages
         wmin_global = jnp.min(jnp.where(
             jnp.arange(n_pages, dtype=jnp.int32) < n_slow,
-            table[:, table_lib.WEAR], 2 ** 30))
+            table_lib.column(table, table_lib.WEAR), 2 ** 30))
         min_wear = jnp.where(do_decay, wmin_global, sc.min_wear)
     return table, plan.dma, plan.done, now, last_ret, min_wear, \
         plan.tombstone
@@ -446,7 +463,7 @@ def retire_phase(cfg: EmulatorConfig, params: RuntimeParams,
     Returns ``(table, rescue_page, fault_cursor, retired_page)`` with
     ``retired_page`` = the page marked this boundary, else -1.
     """
-    n_pages = table.shape[0]
+    n_pages = table_lib.n_pages(table)
     free = rescue_page < 0
 
     # FaultPlan death detector (serialized through the cursor).
@@ -456,7 +473,7 @@ def retire_phase(cfg: EmulatorConfig, params: RuntimeParams,
     due = (fault_cursor < nd) & (deaths[cur, 0] <= sc.chunk_idx)
     consume = due & free
     ev_p = jnp.clip(deaths[cur, 1], 0, n_pages - 1)
-    ev_flags = table[ev_p, table_lib.FLAGS]
+    ev_flags = table_lib.lane_at(table, ev_p, table_lib.FLAGS)
     death_fire = consume & \
         ((ev_flags & (table_lib.POISONED | table_lib.RETIRED)) == 0)
     fault_cursor = fault_cursor + consume.astype(jnp.int32)
@@ -467,9 +484,10 @@ def retire_phase(cfg: EmulatorConfig, params: RuntimeParams,
         page, jnp.stack([jnp.maximum(a, 0), jnp.maximum(b, 0)])])
     cand_ok = jnp.concatenate([valid, jnp.stack([a >= 0, b >= 0])])
     cand = jnp.clip(cand, 0, n_pages - 1)
-    rows = table[cand]
-    wear = table[jnp.where(table_lib.device(rows) == SLOW,
-                           table_lib.frame(rows), 0), table_lib.WEAR]
+    rows = table_lib.rows(table, cand)
+    wear = table_lib.lane_at(
+        table, jnp.where(table_lib.device(rows) == SLOW,
+                         table_lib.frame(rows), 0), table_lib.WEAR)
     over = cand_ok & (params.endurance_budget > 0) & \
         (table_lib.device(rows) == SLOW) & \
         (wear > params.endurance_budget) & \
@@ -480,10 +498,10 @@ def retire_phase(cfg: EmulatorConfig, params: RuntimeParams,
 
     fire = death_fire | wear_fire
     p_ret = jnp.where(death_fire, ev_p, cand[j])
-    new_fl = (table[p_ret, table_lib.FLAGS] | table_lib.POISONED) & \
-        ~table_lib.PINNED
-    table = table.at[jnp.where(fire, p_ret, n_pages),
-                     table_lib.FLAGS].set(new_fl, mode="drop")
+    new_fl = (table_lib.lane_at(table, p_ret, table_lib.FLAGS)
+              | table_lib.POISONED) & ~table_lib.PINNED
+    table = table_lib.set_at(table, jnp.where(fire, p_ret, n_pages),
+                             table_lib.FLAGS, new_fl)
     rescue_page = jnp.where(fire, p_ret, rescue_page)
     return table, rescue_page, fault_cursor, jnp.where(fire, p_ret, -1)
 
@@ -529,7 +547,8 @@ def policy_phase(cfg: EmulatorConfig, params: RuntimeParams,
     # Post-policy proposal mask: device sanity plus FLAGS enforcement — a
     # pinned candidate or victim vetoes the swap no matter what the
     # policy proposed (maybe_start re-checks the same pin bits).
-    cand_row, victim_row = table[cand], table[victim]
+    cand_row = table_lib.rows(table, cand)
+    victim_row = table_lib.rows(table, victim)
     unpinned = ~(table_lib.is_pinned(cand_row) |
                  table_lib.is_pinned(victim_row))
     want = p_want & any_valid & unpinned & \
@@ -539,12 +558,13 @@ def policy_phase(cfg: EmulatorConfig, params: RuntimeParams,
     # Rescue migration override (exactly no-effect while the register is
     # idle — every committed value reduces to the policy's).
     pending = rescue_page >= 0
-    resc = jnp.clip(rescue_page, 0, table.shape[0] - 1)
-    r_slow = table_lib.device(table[resc]) == SLOW
+    n_pages = table_lib.n_pages(table)
+    resc = jnp.clip(rescue_page, 0, n_pages - 1)
+    r_slow = table_lib.device(table_lib.rows(table, resc)) == SLOW
     r_victim, r_found, r_skip = policies_lib._clock_victim(
         table, sc.clock_ptr, params.n_fast_pages)
-    pg = jnp.clip(page, 0, table.shape[0] - 1)
-    rows_pg = table[pg]
+    pg = jnp.clip(page, 0, n_pages - 1)
+    rows_pg = table_lib.rows(table, pg)
     donor_ok = valid & (table_lib.device(rows_pg) == SLOW) & \
         ((table_lib.flags(rows_pg) &
           (table_lib.PINNED | table_lib.RETIRED | table_lib.POISONED)) == 0)

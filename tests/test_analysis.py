@@ -127,8 +127,9 @@ def test_schedule_checker_clean_on_good_step():
 
 
 def test_schedule_checker_flags_flattened_commit():
-    """The commit through a flat ``reshape(-1)`` view (the pre-2-D form)
-    is a finding: on a TPU the flat view relays out the padded table."""
+    """A commit through a ``reshape(-1)`` view of the packed table made
+    inside the step is a finding: on a TPU the reshape relays out the
+    padded table (the scan carries the flat form instead)."""
     import jax
     import jax.numpy as jnp
 
@@ -149,6 +150,67 @@ def test_schedule_checker_flags_flattened_commit():
     assert sum("reshape of the table" in m and "128 lanes" in m
                for m in msgs) == 2, msgs
     assert any("not a 2-D (row, lane) scatter-add" in m for m in msgs), msgs
+
+
+def test_schedule_checker_clean_on_flat_carried_step():
+    """The flat lane-major form the scan carries: gathers, ONE 1-D
+    scatter-add, a one-word post-commit stamp and a lane read of the
+    committed table keep the schedule."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis import schedule
+
+    n = 16
+
+    def flat_step(table, pages, w):
+        hot = table[2 * n + pages]  # gather before the commit
+        idx = jnp.concatenate([2 * n + pages, 5 * n + pages])
+        t2 = table.at[idx].add(jnp.concatenate([w + hot, w]), mode="drop")
+        t2 = t2.at[6 * n + pages[0]].set(1, mode="drop")  # the stamp
+        return jnp.min(t2[3 * n:4 * n])  # committed-table lane read
+
+    i32 = jnp.int32
+    jaxpr = jax.make_jaxpr(flat_step)(
+        jnp.zeros((8 * n,), i32), jnp.arange(4, dtype=i32),
+        jnp.ones(4, i32))
+    assert schedule.check_jaxpr_schedule(jaxpr, 0, label="flat") == []
+
+
+def test_schedule_pass_checks_the_flat_scan_carry():
+    """The pass traces the scan path with either carried form and finds
+    the commit in its body; converting back to packed inside the step
+    would be a finding."""
+    import jax.numpy as jnp
+
+    from repro.analysis import schedule
+    from repro.analysis.common import scan_body_info
+    from repro.core.config import small_platform
+    from repro.core.emulator import as_registry
+
+    cfg = small_platform()
+    for flat, shape in ((True, (8 * cfg.n_pages,)),
+                        (False, (cfg.n_pages, 8))):
+        info, err = scan_body_info(cfg, as_registry(None), flat=flat)
+        assert err is None, err
+        carry = info["body"].invars[info["table_index"]]
+        assert tuple(carry.aval.shape) == shape
+        assert schedule.check_jaxpr_schedule(
+            info["body"], info["table_index"], label="scan-path") == []
+
+    import jax
+
+    def repacking_step(table, pages, w):
+        packed = table.reshape(8, -1).T
+        return packed.at[pages, 2].add(w, mode="drop")
+
+    jaxpr = jax.make_jaxpr(repacking_step)(
+        jnp.zeros((8 * cfg.n_pages,), jnp.int32),
+        jnp.arange(4, dtype=jnp.int32), jnp.ones(4, jnp.int32))
+    msgs = [f.message for f in
+            schedule.check_jaxpr_schedule(jaxpr, 0, label="repack")]
+    assert any("reshape of the table inside the step" in m
+               for m in msgs), msgs
 
 
 def test_schedule_checker_flags_missing_commit():

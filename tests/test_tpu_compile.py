@@ -11,6 +11,7 @@ load the TPU library, and every test worker imports this file.
 Code that asks ``jax.default_backend()`` still sees the CPU here, so the
 kernel selection is steered by monkeypatching in the test.
 """
+import math
 import os
 import re
 
@@ -21,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import Engine
 from repro.core import Trace, paper_platform
+from repro.core import emulator
 from repro.core.emulator import _emulate_impl, entry_point
 from repro.kernels import chunk_step, ops
 from repro.sweep import build_points
@@ -164,6 +166,126 @@ def test_commit_leaves_the_table_unpadded(run_program, sweep_program,
     found = _padded_table_relayouts(compiled.as_text(),
                                     paper_platform().n_pages)
     assert len(found) <= allowed, found
+
+
+def _computations(hlo: str):
+    """``({name: instruction lines}, entry name)`` of an HLO module's
+    text."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            head = line.split(" ", 2)
+            name = (head[1] if head[0] == "ENTRY" else head[0]).lstrip("%")
+            cur = comps.setdefault(name, [])
+            if head[0] == "ENTRY":
+                entry = name
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps, entry
+
+
+_CALLEE = re.compile(r"\b(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _reachable(comps: dict, root: str) -> set:
+    """``root`` and every computation it calls, however deep."""
+    seen, todo = {root}, [root]
+    while todo:
+        for line in comps[todo.pop()]:
+            names = _CALLEE.findall(line)
+            for group in _BRANCHES.findall(line):
+                names += re.findall(r"%([\w.\-]+)", group)
+            for name in names:
+                if name in comps and name not in seen:
+                    seen.add(name)
+                    todo.append(name)
+    return seen
+
+
+def _elements(shape: str) -> int:
+    m = re.match(r"[a-z]+\d*\[([\d,]*)\]", shape)
+    if not m:
+        return 0
+    return math.prod(int(d) for d in m.group(1).split(",") if d)
+
+
+def _table_sized_moves_in_scan(hlo: str, n_pages: int) -> list[str]:
+    """The ``copy``, ``reshape`` and ``dynamic-update-slice`` operations
+    with an operand or result of the table's size (``n_pages * 8``
+    elements) anywhere inside the chunk scan's loop body: the while loop
+    of the entry computation whose body holds the commit scatter. Left
+    out: an in-place update of at most one lane of the flat carried
+    table (``s32[8 * n_pages]``), which is the decay's lane shift and the
+    retire stamp's FLAGS word."""
+    comps, entry = _computations(hlo)
+    bodies = [m.group(1) for line in comps[entry]
+              for m in [re.search(r" while\(.*\bbody=%([\w.\-]+)", line)]
+              if m]
+    scan = [b for b in bodies
+            if any(" scatter(" in line for c in _reachable(comps, b)
+                   for line in comps[c])]
+    assert len(scan) == 1, f"scan loop not found among {bodies}"
+    size, found = n_pages * 8, []
+    for comp in sorted(_reachable(comps, scan[0])):
+        shapes = {}
+        for line in comps[comp]:
+            m = _HLO_INSTR.match(line)
+            if not m:
+                continue
+            name, shape, opcode, args = m.groups()
+            shapes[name] = shape
+            if opcode not in ("copy", "reshape", "dynamic-update-slice"):
+                continue
+            operands = [shapes.get(a.strip().lstrip("%"), "")
+                        for a in args.split(",")]
+            if size not in [_elements(s) for s in [shape, *operands]]:
+                continue
+            if (opcode == "dynamic-update-slice"
+                    and re.match(rf"s32\[{size}\]", shape)
+                    and _elements(operands[1]) <= n_pages):
+                continue
+            found.append(f"{comp}: {name} = {shape} {opcode}")
+    return found
+
+
+@pytest.fixture(scope="module")
+def flat_run_program(one_chip):
+    """The donated ``Engine.run`` program, chunk 512, for the smallest
+    table that the scan carries in the flat lane-major form
+    (``emulator.FLAT_CARRY_MIN_BYTES``): the paper's tiers with the slow
+    tier grown to reach it."""
+    paper = paper_platform().with_(chunk=512)
+    assert not emulator.carries_flat_table(paper)
+    n_pages = -(-emulator.FLAT_CARRY_MIN_BYTES // (8 * 4))
+    cfg = paper.with_(n_slow_pages=n_pages - paper.n_fast_pages)
+    engine = Engine(cfg)
+    assert emulator.carries_flat_table(engine._static)
+    spec = lambda tree: jax.tree.map(lambda x: _spec(x, one_chip), tree)
+    fn = engine._entry_for(N_REQ, carried=True, donate=True)
+    compiled = fn.lower(
+        engine._static, engine.registry, _trace_spec(N_REQ, one_chip),
+        _valid_spec(N_REQ, one_chip),
+        spec(jax.eval_shape(engine.init_state)), spec(engine.params),
+        None).compile()
+    return compiled, engine._static.n_pages
+
+
+def test_scan_body_moves_no_table(flat_run_program):
+    """From ``FLAT_CARRY_MIN_BYTES`` of table up, the donated
+    ``Engine.run`` program carries the table through the chunk scan in
+    the flat lane-major form that XLA's scatter works on, so the loop
+    body copies, reshapes and rewrites no table-sized buffer around the
+    commit: the only table-sized updates left are in place and at most
+    one lane wide. A packed carry puts a ``copy`` and a ``reshape`` of
+    the table in the body and, at server size, a loop of lane-wide
+    writebacks (PERF.md, section 5)."""
+    compiled, n_pages = flat_run_program
+    _check_default_path(compiled)
+    found = _table_sized_moves_in_scan(compiled.as_text(), n_pages)
+    assert found == [], found
 
 
 def test_tpu_selection_rule(monkeypatch):
